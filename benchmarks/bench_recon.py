@@ -1,8 +1,7 @@
 """FBP hot-spot benchmark: backprojection kernel (interpret mode) vs
 pure-jnp reference, plus the fused correction kernel, with derived
-throughput.  On real TPU the Pallas path replaces the gather-bound ref
-with MXU matmuls; interpret-mode wall time here only validates cost
-ratios, not absolute speed."""
+throughput.  CPU only: the kernel runs interpreted, so its wall time
+checks the path, not the speed of the compiled TPU kernel."""
 from __future__ import annotations
 
 import time
@@ -40,7 +39,7 @@ def run(report):
     t_pal = _time(lambda s: backproject(s, angles, N, use_pallas=True,
                                         interpret=True), sino)
     report("fbp_pallas_interpret", t_pal * 1e6,
-           "interpret-mode correctness path (TPU target: MXU matmul)")
+           "interpret-mode correctness path")
 
     raw = jnp.asarray(rng.integers(100, 40000, size=(16, 64, 512))
                       .astype(np.uint16))

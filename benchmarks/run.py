@@ -1,6 +1,8 @@
 """Benchmark harness — one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV (plus the Fig-9 profile chart).
+
+CPU-only (JAX_PLATFORMS=cpu) until ROADMAP S1's on-chip benchmark replaces it.
 """
 from __future__ import annotations
 

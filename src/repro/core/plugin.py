@@ -248,6 +248,14 @@ class BasePlugin:
         return (f"{type(self).__module__}.{type(self).__qualname__}",
                 params_j, tuple(unsignable), statics)
 
+    def persistable(self) -> bool:
+        """Whether another process may reuse a compiled step of this
+        plugin (the persistent executable tier).  Only when its
+        signature names no process-local object and its class is part
+        of this package, whose source the tier's fingerprint covers."""
+        return (type(self).__module__.startswith("repro.")
+                and not self.cache_signature()[2])
+
     def __repr__(self):
         return f"{type(self).__name__}({self.name})"
 
@@ -342,6 +350,9 @@ class LambdaFilter(BaseFilter):
         return self._fn(frames[0])
 
     _fn_tokens = iter(range(1, 1 << 62))
+
+    def persistable(self) -> bool:
+        return False          # the token below is local to this process
 
     def cache_signature(self):
         # the wrapped callable is invisible to the default signature;

@@ -195,6 +195,20 @@ class ShardedTransport(Transport):
                                     self._sharding(pat, data_axis))
         return ds.backing
 
+    def _check_divisible(self, plugin: BasePlugin) -> None:
+        """Refuse datasets whose slices cannot be laid evenly over the
+        mesh's data axis (each device holds an equal share)."""
+        da = plugin.driver.data_axis
+        n = self.mesh.shape[da] if da in self.mesh.axis_names else 1
+        for pd in (*plugin.in_data, *plugin.out_data):
+            dims = pd.pattern.slice_dims
+            if dims and pd.dataset.shape[dims[0]] % n:
+                raise ValueError(
+                    f"plugin {plugin.name}: dataset {pd.dataset.name!r} has "
+                    f"{pd.dataset.shape[dims[0]]} slices along dim "
+                    f"{dims[0]} ({pd.pattern_name}), which do not divide "
+                    f"over the {n} devices of mesh axis {da!r}")
+
     def _plugin_fn(self, plugin: BasePlugin):
         """Traceable (consts, *arrays) -> outs.  ``consts`` is the
         plugin's :meth:`jit_constants` dict passed as jit ARGUMENTS (not
@@ -207,39 +221,64 @@ class ShardedTransport(Transport):
         out_dtypes = [pd.dataset.dtype for pd in plugin.out_data]
         m = plugin.in_data[0].n_frames if plugin.in_data else 1
         const_keys = tuple(sorted(plugin.jit_constants()))
+        self._check_divisible(plugin)
 
-        def fn(consts, *arrays):
+        def per_frames(consts, *frames):
             saved = {k: getattr(plugin, k) for k in const_keys}
             for k in const_keys:
                 setattr(plugin, k, consts[k])
             try:
-                frames = [p.to_frames(a) for p, a in zip(in_pats, arrays)]
                 nf = frames[0].shape[0]
                 if m == 1:
                     res = jax.vmap(
                         lambda *fs: _as_list(
                             plugin.process_frames([f[None] for f in fs])),
                     )(*frames)
-                    res = [r.reshape((nf,) + r.shape[2:]) for r in res]
                 else:
-                    if nf % m:
-                        raise ValueError(
-                            f"sharded transport requires n_frames({m}) | "
-                            f"total frames({nf}) for plugin {plugin.name}")
                     grouped = [f.reshape((nf // m, m) + f.shape[1:])
                                for f in frames]
                     res = jax.vmap(
                         lambda *fs: _as_list(plugin.process_frames(list(fs))),
                     )(*grouped)
-                    res = [r.reshape((nf,) + r.shape[2:]) for r in res]
-                outs = []
-                for r, pat, shp, dt in zip(res, out_pats, out_shapes,
-                                           out_dtypes):
-                    outs.append(pat.from_frames(r, shp).astype(dt))
-                return tuple(outs)
+                return tuple(r.reshape((nf,) + r.shape[2:]) for r in res)
             finally:
                 for k, v in saved.items():
                     setattr(plugin, k, v)
+
+        da = plugin.driver.data_axis
+        n_shards = (self.mesh.shape[da] if da in self.mesh.axis_names
+                    else 1)
+
+        def fn(consts, *arrays):
+            frames = [p.to_frames(a) for p, a in zip(in_pats, arrays)]
+            nf = frames[0].shape[0]
+            if nf % m:
+                raise ValueError(
+                    f"sharded transport requires n_frames({m}) | "
+                    f"total frames({nf}) for plugin {plugin.name}")
+            if n_shards == 1:
+                res = per_frames(consts, *frames)
+            else:
+                # frames are independent: each device maps its own share,
+                # so per-frame work (FFTs, Pallas kernels, which the
+                # partitioner cannot split) runs unpartitioned per
+                # device.  The frame axis is zero-padded to whole groups
+                # on every device and the padding cropped afterwards.
+                pad = -nf % (n_shards * m)
+                frames = [jnp.pad(f, ((0, pad),) + ((0, 0),) * (f.ndim - 1))
+                          for f in frames]
+                res = jax.shard_map(
+                    per_frames, mesh=self.mesh,
+                    in_specs=(PartitionSpec(),)
+                    + (PartitionSpec(da),) * len(frames),
+                    out_specs=PartitionSpec(da),
+                    check_vma=False)(consts, *frames)
+                res = [r[:nf] for r in res]
+            outs = []
+            for r, pat, shp, dt in zip(res, out_pats, out_shapes,
+                                       out_dtypes):
+                outs.append(pat.from_frames(r, shp).astype(dt))
+            return tuple(outs)
 
         return fn
 
@@ -316,6 +355,7 @@ class ShardedTransport(Transport):
         return jfn.lower(consts, *specs).compile()
 
     def _device_in(self, plugin: BasePlugin) -> list[Any]:
+        self._check_divisible(plugin)
         da = plugin.driver.data_axis
         arrays = []
         for pd in plugin.in_data:
@@ -336,7 +376,7 @@ class ShardedTransport(Transport):
             jfn = self.compile_cache.get_or_build(
                 self._plugin_key(plugin, consts),
                 lambda: self.compile_plugin(plugin, consts=consts),
-                serializable=True)
+                serializable=plugin.persistable())
             outs = list(jfn(consts, *arrays))
         for pd, o in zip(plugin.out_data, outs):
             pd.dataset.backing = o

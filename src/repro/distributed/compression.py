@@ -17,7 +17,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 BLOCK = 256
@@ -97,5 +96,5 @@ def compressed_psum(x: jnp.ndarray, mesh: Mesh, axis: str = "pod"
         out = (qsum.astype(jnp.float32) * scale).reshape(-1)[:n]
         return out.reshape(xs.shape).astype(x.dtype)
 
-    return shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_rep=False)(x)
+    return jax.shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(x)

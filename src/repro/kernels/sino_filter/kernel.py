@@ -25,14 +25,14 @@ def _scale_kernel(re_ref, im_ref, filt_ref, ore_ref, oim_ref):
 @functools.partial(jax.jit, static_argnames=("bf", "interpret"))
 def scale_spectrum_pallas(re: jnp.ndarray, im: jnp.ndarray,
                           filt: jnp.ndarray, *, bf: int = 8,
-                          interpret: bool = True):
-    """re/im (F, NF) spectrum planes × filt (1, NF) -> scaled planes."""
+                          interpret: bool):
+    """re/im (F, NF) spectrum planes × filt (1, NF) -> scaled planes.
+    ``bf`` (frames per block) must be a multiple of 8."""
     f, nf = re.shape
-    bf = min(bf, f)
-    while f % bf:
-        bf //= 2
-    bf = max(1, bf)
-    grid = (f // bf,)
+    # a frame block is a multiple of 8 sublanes or all (few) frames; a
+    # last partial block is padded on read and masked on write
+    bf = f if f <= bf else bf
+    grid = (pl.cdiv(f, bf),)
     return pl.pallas_call(
         _scale_kernel,
         grid=grid,

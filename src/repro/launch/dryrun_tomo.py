@@ -82,8 +82,7 @@ def lower_chain(mesh, use_pallas: bool = False) -> dict:
     # dims, never sharded), so each runs under shard_map — manual SPMD,
     # per-shard local compute, zero replication; the pattern transition
     # between plugins stays a with_sharding_constraint (all-to-all).
-    from jax.experimental.shard_map import shard_map
-
+    
     def local_fn(p_):
         pat_in = p_.in_data[0].pattern
         pat_out = p_.out_data[0].pattern
@@ -102,10 +101,10 @@ def lower_chain(mesh, use_pallas: bool = False) -> dict:
         in_sh_p = tr._sharding(p_.in_data[0].pattern, "data")
         out_sh_p = tr._sharding(p_.out_data[0].pattern, "data")
         mid_sh.append(out_sh_p)
-        wrapped.append(shard_map(local_fn(p_), mesh=mesh,
-                                 in_specs=(in_sh_p.spec,),
-                                 out_specs=in_sh_p.spec,
-                                 check_rep=False))
+        wrapped.append(jax.shard_map(local_fn(p_), mesh=mesh,
+                                     in_specs=(in_sh_p.spec,),
+                                     out_specs=in_sh_p.spec,
+                                     check_vma=False))
 
     def chain(x):
         cur = x

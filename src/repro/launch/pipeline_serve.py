@@ -87,6 +87,7 @@ from ..core import (ChunkedFileTransport, InMemoryTransport, PluginRunner,
 from ..service import (METRICS, CheckpointStore, CompileCache, JobQueue,
                        PipelineClient, PipelineScheduler, PipelineService,
                        ServiceError, to_spec)
+from ..service.compile_cache import setup_compilation_cache
 from ..service.worker import spawn_local_workers
 from ..tomo import standard_chain
 
@@ -299,18 +300,8 @@ def _remote_demo(args) -> None:
             for s in failed:
                 print(s["error"])
             raise SystemExit(f"{len(failed)}/{len(snaps)} jobs failed")
-        if args.verify:
-            worst = 0.0
-            for s in snaps:
-                got = client.result(s["job_id"])
-                ref = PluginRunner(
-                    _chain(args, seed=s["metadata"]["seed"])).run()
-                want = np.asarray(ref["recon"].materialise())
-                np.testing.assert_allclose(got, want, rtol=1e-3,
-                                           atol=1e-4)
-                worst = max(worst, float(np.max(np.abs(got - want))))
-            print(f"verified {len(snaps)} reconstructions against "
-                  f"serial PluginRunner (max |Δ|={worst:.2e})")
+        got = ({s["job_id"]: client.result(s["job_id"]) for s in snaps}
+               if args.verify else {})
         st = client.stats()
         per_worker = {w: s["jobs_done"]
                       for w, s in st["workers"].items()}
@@ -326,6 +317,19 @@ def _remote_demo(args) -> None:
         for p in workers:
             p.wait(timeout=10)
         service.stop()
+    if args.verify:
+        # only now, with the workers gone, may this process take the
+        # device for the reference runs (one process per chip)
+        worst = 0.0
+        for s in snaps:
+            ref = PluginRunner(_chain(args, seed=s["metadata"]["seed"])).run()
+            want = np.asarray(ref["recon"].materialise())
+            np.testing.assert_allclose(got[s["job_id"]], want, rtol=1e-3,
+                                       atol=1e-4)
+            worst = max(worst, float(np.max(np.abs(got[s["job_id"]]
+                                                    - want))))
+        print(f"verified {len(snaps)} reconstructions against "
+              f"serial PluginRunner (max |Δ|={worst:.2e})")
 
 
 # ----------------------------------------------------------------------
@@ -908,6 +912,7 @@ def main(argv: list[str] | None = None) -> None:
     if argv[:1] == ["client"]:
         return _client_main(argv[1:])
     args = _build_parser().parse_args(argv)
+    setup_compilation_cache()
     if args.serve is not None:
         return _serve_main(args)
     if args.workers_remote is not None:

@@ -56,12 +56,59 @@ class StaleExecutable(Exception):
     the caller falls back to a fresh compile."""
 
 
+#: root of the checkout this package runs from (src/repro/service/..)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def cache_root() -> str:
+    """Where this program keeps compiled code: ``JAX_COMPILATION_CACHE_DIR``
+    when it is set, else ``.jax_cache`` at the root of the checkout.  The
+    path is fixed, so what one run caches the next one finds."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
+
+
+def default_executables_dir() -> str:
+    """Default :class:`ExecutableStore` directory, under :func:`cache_root`."""
+    return os.path.join(cache_root(), "executables")
+
+
+def setup_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache at :func:`cache_root`
+    (entry points call this before their first compile).  JAX reads
+    ``JAX_COMPILATION_CACHE_DIR`` itself, so when it is set no directory
+    is set here.  Returns the directory in use."""
+    root = cache_root()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", root)
+    return root
+
+
+def _source_digest() -> str:
+    """sha256 over this package's source files: a persisted executable
+    is only valid for the code that compiled it."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(pkg):
+        dirnames.sort()
+        for name in sorted(filenames):
+            if name.endswith(".py"):
+                path = os.path.join(dirpath, name)
+                h.update(os.path.relpath(path, pkg).encode())
+                with open(path, "rb") as fh:
+                    h.update(fh.read())
+    return h.hexdigest()
+
+
 _fingerprint_cache: dict[str, Any] | None = None
 
 
 def env_fingerprint() -> dict[str, Any]:
     """The toolchain+hardware identity a serialized executable is only
-    valid under: jax/jaxlib versions, backend, and device kinds/count.
+    valid under: jax/jaxlib versions, backend, device kinds/count, and
+    the package source it was compiled from.
     Baked into every payload header AND into
     :func:`executable_signature`, so stale entries are rejected twice
     over (different signature, and a header mismatch on load) rather
@@ -82,6 +129,7 @@ def env_fingerprint() -> dict[str, Any]:
             "backend": jax.default_backend(),
             "devices": sorted({d.device_kind for d in devs}),
             "n_devices": len(devs),
+            "source": _source_digest(),
         }
     return _fingerprint_cache
 
@@ -521,6 +569,11 @@ class CompileCache:
             if payload and self.store.put_bytes(sig, payload):
                 n += 1
         return n
+
+    def items(self) -> list[tuple[Any, Any]]:
+        """Snapshot of the (key, compiled program) pairs held in memory."""
+        with self._lock:
+            return list(self._entries.items())
 
     def __len__(self) -> int:
         with self._lock:

@@ -701,7 +701,8 @@ class WorkerBroker:
                 complete); None disables.
             executables_dir: spool for serialized executables workers
                 upload (``PUT /executables/{sig}``) and fresh workers
-                prefetch (warm pool).  Default: a fresh temp directory.
+                prefetch (warm pool).  Default:
+                :func:`~repro.service.compile_cache.default_executables_dir`.
             executables_max_bytes: LRU retention bound on that spool.
         """
         self.queue = queue
@@ -724,9 +725,9 @@ class WorkerBroker:
         # .npy spool goes with it — otherwise the spool grows for the
         # broker's lifetime (ROADMAP follow-up)
         queue.add_evict_hook(self._gc_spool)
-        from .compile_cache import ExecutableStore
+        from .compile_cache import ExecutableStore, default_executables_dir
         self.executables = ExecutableStore(
-            executables_dir or tempfile.mkdtemp(prefix="pipeline-exe-"),
+            executables_dir or default_executables_dir(),
             max_bytes=executables_max_bytes)
         self.executables_uploaded = 0
         self.executables_served = 0

@@ -164,7 +164,8 @@ class PipelineService:
         ``GET /jobs/{id}/trace`` falls back to it.
         ``executables_dir`` roots the persistent executable tier: in
         broker mode it is the broker's upload/prefetch spool
-        (``GET/PUT /executables/{sig}``, default a temp dir); in
+        (``GET/PUT /executables/{sig}``, default
+        :func:`~repro.service.compile_cache.default_executables_dir`); in
         scheduler mode it becomes the service CompileCache's disk store
         so compiled programs survive restarts.
 

@@ -37,10 +37,12 @@ CLI::
 from __future__ import annotations
 
 import argparse
+import glob
 import importlib
 import io
 import os
 import shutil
+import sys
 import tempfile
 import threading
 import time
@@ -55,7 +57,8 @@ from ..core.transport import ChunkedFileTransport, InMemoryTransport, \
 from ..obs.trace import Trace, use_trace
 from .checkpoint import CheckpointStore
 from .client import PipelineClient, ServiceError
-from .compile_cache import CompileCache
+from .compile_cache import (CompileCache, default_executables_dir,
+                            setup_compilation_cache)
 from .job import chain_signature
 from .wire import from_spec, registered_plugins
 
@@ -743,6 +746,51 @@ class PipelineWorker:
 
 
 # ----------------------------------------------------------------------
+def host_chips() -> int:
+    """Accelerator chips on this host, counted from their device nodes
+    (TPU chips appear as ``/dev/accel<n>`` or ``/dev/vfio/<n>``) so that
+    counting does not initialise JAX, which would claim them."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            + len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def _holds_accelerator() -> bool:
+    """Whether this process has already initialised a non-CPU JAX
+    backend (and so holds the host's chips)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "cpu")
+
+
+def check_chip_budget(n: int, env: dict[str, str]) -> None:
+    """Refuse to start ``n`` worker processes with environment ``env``
+    when they would contend for this host's chips.
+
+    A JAX process claims every chip it can see, and a chip serves one
+    process, so at most one accelerator-holding worker may run per host
+    — none while this process holds the chips itself.  Workers whose
+    ``JAX_PLATFORMS`` is ``cpu``, or a host without chips, are not
+    limited."""
+    if n == 0 or env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    chips = host_chips()
+    if chips == 0:
+        return
+    held = _holds_accelerator()
+    allowed = 0 if held else 1
+    if n > allowed:
+        why = ("which this process already holds" if held
+               else "and one process holds them all")
+        raise RuntimeError(
+            f"spawn_local_workers: {n} worker process(es) would share this "
+            f"host's {chips} accelerator chip(s), {why}; at most "
+            f"{allowed} may start here.  Run the workers on the CPU "
+            f"(JAX_PLATFORMS=cpu) or on other hosts.")
+
+
 def spawn_local_workers(url: str, n: int, *, transport: str = "inmemory",
                         checkpoint_dir: str | None = None,
                         shared_fs: bool = False, poll: float = 0.1,
@@ -759,12 +807,14 @@ def spawn_local_workers(url: str, n: int, *, transport: str = "inmemory",
     ``pipeline_serve --workers-remote N`` demo, benchmarks and tests all
     use this.  Each worker is a real OS process (kill one to exercise
     the lease-expiry/resume path).  Returns the ``Popen`` handles;
-    caller terminates them."""
+    caller terminates them.  Raises RuntimeError before starting any
+    when the workers would contend for the host's chips
+    (:func:`check_chip_budget`)."""
     import subprocess
-    import sys
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)
+    check_chip_budget(n, env)
     parts = [src, *pythonpath_extra]
     if env.get("PYTHONPATH"):
         parts.append(env["PYTHONPATH"])
@@ -863,8 +913,8 @@ def main(argv: list[str] | None = None) -> None:
                          "streaming jobs (0 disables previews)")
     ap.add_argument("--executables-dir", default=None,
                     help="local disk tier for serialized executables "
-                         "(sharded transport only; default: a subdir "
-                         "of the worker scratch directory)")
+                         "(sharded transport only; default: "
+                         "'executables' under the compile cache root)")
     ap.add_argument("--cost-analysis",
                     action=argparse.BooleanOptionalAction, default=False,
                     help="attach XLA cost/memory analysis (flops, bytes "
@@ -873,12 +923,12 @@ def main(argv: list[str] | None = None) -> None:
     args = ap.parse_args(argv)
     for mod in args.imports:
         importlib.import_module(mod)
+    setup_compilation_cache()
     scratch = tempfile.mkdtemp(prefix="pipeline-worker-")
     compile_cache = None
     if args.transport == "sharded":
-        exe_dir = args.executables_dir or os.path.join(scratch,
-                                                       "executables")
-        compile_cache = CompileCache(store=exe_dir)
+        compile_cache = CompileCache(
+            store=args.executables_dir or default_executables_dir())
     worker = PipelineWorker(
         args.url,
         # gang execution stacks job inputs — donation would invalidate

@@ -35,26 +35,60 @@ _SHEPP_LOGAN = [
 
 def shepp_logan(n: int, dtype=np.float32) -> np.ndarray:
     """n×n modified Shepp–Logan phantom in [0, ~1]."""
-    ys, xs = np.mgrid[-1:1:n * 1j, -1:1:n * 1j]
+    coord = np.linspace(-1.0, 1.0, n)
     img = np.zeros((n, n), dtype=np.float64)
     for val, a, b, x0, y0, phi in _SHEPP_LOGAN:
         th = math.radians(phi)
         c, s = math.cos(th), math.sin(th)
-        xr = (xs - x0) * c + (ys - y0) * s
-        yr = -(xs - x0) * s + (ys - y0) * c
-        img[(xr / a) ** 2 + (yr / b) ** 2 <= 1.0] += val
+        # rasterise inside the ellipse's bounding box only
+        r = max(a, b)
+        rows = np.flatnonzero(np.abs(coord - y0) <= r)
+        cols = np.flatnonzero(np.abs(coord - x0) <= r)
+        if rows.size == 0 or cols.size == 0:
+            continue
+        ys = coord[rows][:, None] - y0
+        xs = coord[cols][None, :] - x0
+        xr = xs * c + ys * s
+        yr = -xs * s + ys * c
+        box = img[rows[0]:rows[-1] + 1, cols[0]:cols[-1] + 1]
+        box[(xr / a) ** 2 + (yr / b) ** 2 <= 1.0] += val
     return img.astype(dtype)
+
+
+def row_scales(n_rows: int) -> np.ndarray:
+    """Per-row intensity scale of :func:`phantom_stack`."""
+    return 0.5 + 0.5 * (np.arange(n_rows) + 1) / n_rows
 
 
 def phantom_stack(n: int, n_rows: int, dtype=np.float32) -> np.ndarray:
     """(n_rows, n, n) phantom volume: Shepp–Logan modulated per row, so
     adjacent slices differ (tests catch axis mix-ups)."""
-    base = shepp_logan(n, np.float64)
-    rows = []
-    for r in range(n_rows):
-        scale = 0.5 + 0.5 * (r + 1) / n_rows
-        rows.append(base * scale)
-    return np.stack(rows).astype(dtype)
+    base = shepp_logan(n, np.float32)
+    scales = row_scales(n_rows).astype(np.float32)
+    return (base[None] * scales[:, None, None]).astype(dtype, copy=False)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "geom"))
+def shepp_logan_sinogram(n: int, geom: ParallelGeometry) -> jnp.ndarray:
+    """(n_angles, n_det) exact line integrals of the n×n phantom's
+    ellipses, in pixel units — the continuous counterpart of
+    ``forward_project(shepp_logan(n))`` at O(angles·n_det) cost.
+
+    An ellipse of value ρ, semi-axes (a, b) rotated by φ and centred at
+    (x0, y0) has, at angle θ and detector offset τ from its centre's
+    projection, the chord 2ab·√(s² − τ²)/s² with
+    s² = a²cos²(θ−φ) + b²sin²(θ−φ)."""
+    h = 2.0 / (n - 1)                    # pixel pitch in phantom units
+    theta = jnp.asarray(geom.angles, jnp.float32)[:, None]
+    t = (jnp.arange(geom.n_det, dtype=jnp.float32)
+         - (geom.n_det - 1) / 2.0)[None, :] * h
+    sino = jnp.zeros((geom.n_angles, geom.n_det), jnp.float32)
+    for val, a, b, x0, y0, phi in _SHEPP_LOGAN:
+        rel = theta - math.radians(phi)
+        s2 = (a * jnp.cos(rel)) ** 2 + (b * jnp.sin(rel)) ** 2
+        tau = t - (x0 * jnp.cos(theta) + y0 * jnp.sin(theta))
+        sino += val * 2 * a * b * jnp.sqrt(jnp.maximum(s2 - tau ** 2, 0)) / s2
+    return sino / h
 
 
 # ----------------------------------------------------------------------
@@ -110,6 +144,40 @@ def forward_project(volume: np.ndarray, geom: ParallelGeometry
     return np.asarray(jnp.transpose(sinos, (1, 0, 2)))
 
 
+@jax.jit
+def _counts(proj, row_scale, dark, flat, mu):
+    """Detector counts dark + (flat − dark)·exp(−μ·path), path =
+    proj (θ, 1 | y, x) × row_scale (y,), clipped to the uint16 range."""
+    path = proj * row_scale[None, :, None]
+    counts = dark[None] + (flat[None] - dark[None]) * jnp.exp(-mu * path)
+    return jnp.clip(counts, 0, 65535)
+
+
+def _raw_scan(proj, row_scale, *, noise: float, seed: int, mu: float,
+              i0: float = 40000.0, dark_level: float = 96.0
+              ) -> dict[str, np.ndarray]:
+    rng = np.random.default_rng(seed)
+    shape = (row_scale.shape[0], proj.shape[2])        # (y, x)
+    flat = np.full(shape, i0, dtype=np.float64)
+    flat += rng.normal(0, i0 * 0.002, size=flat.shape)
+    dark = np.full(shape, dark_level, dtype=np.float64)
+    counts = _counts(jnp.asarray(proj, jnp.float32),
+                     jnp.asarray(row_scale, jnp.float32),
+                     jnp.asarray(dark, jnp.float32),
+                     jnp.asarray(flat, jnp.float32), mu)
+    if noise > 0:
+        counts = rng.poisson(np.asarray(counts) / noise) * noise
+        data = np.clip(counts, 0, 65535).astype(np.uint16)
+    else:
+        data = np.asarray(counts.astype(jnp.uint16))
+    return {
+        "data": data,
+        "dark": np.clip(dark, 0, 65535).astype(np.uint16),
+        "flat": np.clip(flat, 0, 65535).astype(np.uint16),
+        "mu": mu,
+    }
+
+
 def simulate_raw_scan(volume: np.ndarray, geom: ParallelGeometry, *,
                       i0: float = 40000.0, dark_level: float = 96.0,
                       noise: float = 0.0, seed: int = 0,
@@ -118,18 +186,22 @@ def simulate_raw_scan(volume: np.ndarray, geom: ParallelGeometry, *,
     transmission I = dark + (I0-dark)·exp(-μ·path) with optional Poisson
     noise; plus dark/flat fields — i.e. what a loader plugin would see."""
     proj = forward_project(volume, geom)           # path lengths (θ, y, x)
-    rng = np.random.default_rng(seed)
-    flat = np.full(proj.shape[1:], i0, dtype=np.float64)
-    flat += rng.normal(0, i0 * 0.002, size=flat.shape)
-    dark = np.full(proj.shape[1:], dark_level, dtype=np.float64)
-    trans = np.exp(-mu * proj.astype(np.float64))
-    counts = dark[None] + (flat[None] - dark[None]) * trans
-    if noise > 0:
-        counts = rng.poisson(np.clip(counts / noise, 0, None)) * noise
-    return {
-        "data": np.clip(counts, 0, 65535).astype(np.uint16),
-        "dark": np.clip(dark, 0, 65535).astype(np.uint16),
-        "flat": np.clip(flat, 0, 65535).astype(np.uint16),
-        "mu": mu,
-        "truth": np.asarray(volume, dtype=np.float32),
-    }
+    scan = _raw_scan(proj, np.ones(proj.shape[1]), i0=i0,
+                     dark_level=dark_level, noise=noise, seed=seed, mu=mu)
+    scan["truth"] = np.asarray(volume, dtype=np.float32)
+    return scan
+
+
+def simulate_phantom_scan(geom: ParallelGeometry, *, noise: float = 0.0,
+                          seed: int = 0, mu: float = 0.02
+                          ) -> dict[str, np.ndarray]:
+    """:func:`simulate_raw_scan` of ``phantom_stack(n_det, n_rows)``,
+    built from the closed-form sinogram of one slice (rows are scaled
+    copies), so a beamline-size scan costs O(angles·rows·n_det) on the
+    device instead of a projector pass per row."""
+    n = geom.n_det
+    proj = shepp_logan_sinogram(n, geom)[:, None, :]      # on the device
+    scan = _raw_scan(proj, row_scales(geom.n_rows), noise=noise, seed=seed,
+                     mu=mu)
+    scan["truth"] = phantom_stack(n, geom.n_rows)
+    return scan

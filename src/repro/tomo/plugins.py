@@ -23,7 +23,7 @@ from ..kernels.correction.ops import correct
 from ..kernels.sino_filter.ops import filter_sino
 from ..kernels.sino_filter.ref import make_filter
 from .geometry import ParallelGeometry
-from .phantom import simulate_raw_scan
+from .phantom import simulate_phantom_scan
 
 
 # ----------------------------------------------------------------------
@@ -40,11 +40,9 @@ class SyntheticTomoLoader(BaseLoader):
         p = self.params
         scan = p["scan"]
         if scan is None:
-            from .phantom import phantom_stack
             geom = ParallelGeometry(p["n_angles"], p["n_det"], p["n_rows"])
-            vol = phantom_stack(p["n_det"], p["n_rows"])
-            scan = simulate_raw_scan(vol, geom, noise=p["noise"],
-                                     seed=p["seed"])
+            scan = simulate_phantom_scan(geom, noise=p["noise"],
+                                         seed=p["seed"])
         else:
             geom = ParallelGeometry(scan["data"].shape[0],
                                     scan["data"].shape[2],
@@ -150,7 +148,11 @@ class RingRemoval(BaseFilter):
         pad = k // 2
         padded = jnp.pad(col_mean, ((0, 0), (0, 0), (pad, pad)), mode="edge")
         kern = jnp.ones((k,), block.dtype) / k
-        smooth = jax.vmap(lambda r: jnp.convolve(r, kern, mode="valid"))(
+        # full f32: a TPU's default precision rounds the operands to
+        # bf16, by rules that follow the compiled layout (one chip and a
+        # mesh then disagree by ~1e-3 of the volume's range)
+        smooth = jax.vmap(lambda r: jnp.convolve(
+            r, kern, mode="valid", precision=jax.lax.Precision.HIGHEST))(
             padded[:, 0, :])[:, None, :]
         stripe = col_mean - smooth
         return block - self._strength * stripe

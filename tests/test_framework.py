@@ -1,5 +1,9 @@
 """Framework behaviour: transports agree, fusion agrees, replacement
 semantics, multi-dataset chains (Fig 10), profiler."""
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -87,6 +91,48 @@ def test_fusion_matches_unfused(data):
     PluginRunner(_chain(data), ShardedTransport(mesh), fuse=True).run()
     fused = CaptureSaver.captured["tomo"]
     np.testing.assert_allclose(fused, data * 2 + 1, rtol=1e-5)
+
+
+_FOUR_DEVICE_CHAIN = """
+import jax
+jax.config.update("jax_num_cpu_devices", 4)
+import numpy as np
+from jax.sharding import Mesh
+from repro.core import PluginRunner, ShardedTransport
+from test_framework import _chain
+
+mesh = Mesh(np.asarray(jax.devices()), ("data",))
+assert mesh.size == 4
+# 12 projections and 20 rows divide over 4 devices, but not into whole
+# groups of 2 or 4 frames on every device
+a = np.random.default_rng(0).normal(size=(12, 20, 4)).astype(np.float32)
+for frames in (1, 2, 4):
+    out = PluginRunner(_chain(a, frames), ShardedTransport(mesh)).run()
+    np.testing.assert_allclose(np.asarray(out["tomo"].materialise()),
+                               a * 2 + 1, rtol=1e-6)
+try:
+    PluginRunner(_chain(a[:6]), ShardedTransport(mesh)).run()
+except ValueError as e:
+    assert "has 6 slices" in str(e) and "4 devices" in str(e), e
+else:
+    raise AssertionError("6 projections were laid over 4 devices")
+"""
+
+
+def test_sharded_transport_pads_indivisible_frames():
+    """On a 4-device data mesh, frame counts that do not fill whole
+    groups of frames on every device still map per device and give the
+    single-device result; slice counts that do not divide over the
+    devices are refused with the counts."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(here), "src"), here,
+                    os.environ.get("PYTHONPATH", "")]))
+    proc = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_CHAIN],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
 
 
 def test_multi_frame_processing(data):

@@ -6,8 +6,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.backproject.kernel import backproject_pallas
-from repro.kernels.backproject.ops import backproject
+from repro.kernels.backproject.kernel import (backproject_pallas,
+                                              detector_padding)
+from repro.kernels.backproject.ops import (VMEM_BUDGET_BYTES, _pick_blocks,
+                                           backproject)
 from repro.kernels.backproject.ref import backproject_ref
 from repro.kernels.correction.kernel import correct_pallas
 from repro.kernels.correction.ref import correct_ref
@@ -20,10 +22,11 @@ from repro.kernels.sino_filter.ops import filter_sino
 
 # ----------------------------------------------------------------- FBP
 @pytest.mark.parametrize("A,D,N,bh,bw,ba", [
-    (16, 32, 32, 8, 16, 4),
-    (32, 64, 64, 8, 32, 16),
-    (24, 48, 48, 16, 16, 8),
-    (8, 128, 64, 8, 64, 2),
+    (16, 32, 32, 8, 128, 8),
+    (32, 64, 64, 8, 128, 16),
+    (24, 48, 48, 16, 128, 8),
+    (8, 128, 64, 8, 128, 8),
+    (20, 64, 200, 64, 256, 8),     # image wider than the detector
 ])
 def test_backproject_shapes(rng, A, D, N, bh, bw, ba):
     sino = jnp.asarray(rng.normal(size=(A, D)).astype(np.float32))
@@ -35,6 +38,20 @@ def test_backproject_shapes(rng, A, D, N, bh, bw, ba):
                              interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("N,A,D", [(2048, 3072, 2048), (2000, 1800, 2000),
+                                   (32, 16, 32), (130, 33, 100)])
+def test_pick_blocks_tiles_within_budget(N, A, D):
+    bh, bw, ba = _pick_blocks(N, A, D)
+    assert bh % 8 == 0 and bw % 128 == 0 and ba % 8 == 0
+    _, length = detector_padding(N, D, (D - 1) / 2.0, bh, bw)
+    assert 4 * (2 * 2 * ba * length + 2 * bh * bw) <= VMEM_BUDGET_BYTES
+
+
+def test_pick_blocks_refuses_rows_past_vmem():
+    with pytest.raises(ValueError, match="VMEM"):
+        _pick_blocks(64, 64, 200_000)
 
 
 def test_backproject_ops_batched(rng):

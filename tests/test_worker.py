@@ -460,3 +460,39 @@ def test_inprocess_worker_round_trip(broker):
     workers = client.workers()
     assert workers["lib-w"]["jobs_done"] == 1
     assert client.stats()["jobs_done"] == 1
+
+
+# ============================================== one process per chip
+@pytest.fixture
+def four_chip_host(monkeypatch):
+    import subprocess
+    from repro.service import worker as worker_mod
+    monkeypatch.setattr(worker_mod, "host_chips", lambda: 4)
+    monkeypatch.setattr(worker_mod, "_holds_accelerator", lambda: False)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+
+    def no_spawn(*a, **kw):
+        raise AssertionError("a worker process was started")
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    return worker_mod
+
+
+def test_spawn_refuses_workers_sharing_the_chips(four_chip_host):
+    with pytest.raises(RuntimeError,
+                       match=r"2 worker process\(es\).*4 accelerator chip"):
+        spawn_local_workers("http://127.0.0.1:1", 2, transport="sharded")
+
+
+def test_chip_budget_allows_one_worker_unless_parent_holds_chips(
+        four_chip_host, monkeypatch):
+    four_chip_host.check_chip_budget(1, {})
+    monkeypatch.setattr(four_chip_host, "_holds_accelerator", lambda: True)
+    with pytest.raises(RuntimeError, match="at most 0"):
+        four_chip_host.check_chip_budget(1, {})
+
+
+def test_chip_budget_ignores_cpu_workers_and_chipless_hosts(
+        four_chip_host, monkeypatch):
+    four_chip_host.check_chip_budget(8, {"JAX_PLATFORMS": "cpu"})
+    monkeypatch.setattr(four_chip_host, "host_chips", lambda: 0)
+    four_chip_host.check_chip_budget(8, {})
