@@ -254,13 +254,7 @@ class PluginRunner:
         devices = getattr(getattr(self.transport, "mesh", None), "size", 1)
         if len(group) == 1:
             p = group[0]
-            # cost analysis (when the transport offers it) runs BEFORE
-            # the timer so its one-off AOT compile never pollutes the
-            # process span it annotates
-            cost = (self.transport.plugin_cost(p)
-                    if hasattr(self.transport, "plugin_cost") else None)
-            with self.profiler.timer(p.name, "process", devices,
-                                     **(cost or {})):
+            with self.profiler.timer(p.name, "process", devices):
                 self.transport.run_plugin(p)
         else:
             label = "+".join(p.name for p in group)

@@ -5,19 +5,20 @@ processing step took.  Since the telemetry layer landed
 (``repro.obs``), the profiler is a thin *view* over a
 :class:`~repro.obs.trace.Trace` rather than a parallel event system:
 every ``timer()`` records a ``plugin.<name>.<phase>`` span (epoch
-timestamps, so spans from different processes align on one timeline),
-and the classic API — ``record``/``totals``/``report``/``save`` — keeps
-working on top of it.  A :class:`PluginRunner` handed a profiler whose
-trace is the job's trace therefore feeds the distributed timeline at
+timestamps, so spans from different processes align on one timeline;
+mirrored into JAX's profiler while a session runs), and the classic
+API — ``record``/``totals``/``report`` — keeps working on top of it.
+A :class:`PluginRunner` handed a profiler whose trace is the job's
+trace therefore feeds the distributed timeline at
 ``GET /jobs/{id}/trace`` for free.
 
-``report()`` renders the Fig-9-style ASCII bar chart; ``save()`` emits
-the historical event-list JSON for the benchmark harness.
+A ``process`` span times the host's dispatch of a step: a jitted step
+returns before the device finishes.  ``report()`` renders the
+Fig-9-style ASCII bar chart.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
 import time
 from typing import Any
 
@@ -146,20 +147,3 @@ class Profiler:
         lines.append("per-phase: " + "  ".join(
             f"{k}={v:.4f}s" for k, v in sorted(phases.items())))
         return "\n".join(lines)
-
-    def save(self, path: str) -> None:
-        with open(path, "w") as fh:
-            json.dump([dataclasses.asdict(e) for e in self.events], fh,
-                      indent=2, default=str)
-
-    @staticmethod
-    def load(path: str) -> "Profiler":
-        p = Profiler()
-        with open(path) as fh:
-            for d in json.load(fh):
-                extra = d.pop("extra", {}) or {}
-                p.record(d["plugin"], d["phase"], d["start"], d["end"],
-                         devices=d.get("devices", 1),
-                         flops=d.get("flops"), bytes=d.get("bytes"),
-                         **extra)
-        return p

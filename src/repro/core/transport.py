@@ -31,11 +31,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..obs.trace import current_trace
+from ..obs.trace import traced
 from .chunking import DEFAULT_CACHE_BYTES, optimise_chunks
 from .dataset import DataSet
 from .patterns import Pattern
 from .plugin import BasePlugin
+
+
+def _named(fn, name: str):
+    """``fn`` renamed ``name``: jit names its program ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def _as_list(x):
@@ -86,7 +92,8 @@ class LocalCompileCache:
         self.hits = 0
         self.misses = 0
 
-    def get_or_build(self, key, builder, serializable=False):
+    def get_or_build(self, key, builder, serializable=False,
+                     step: str | None = None):
         # ``serializable`` marks builders whose output could go to the
         # process-level cache's persistent tier; the local cache has no
         # such tier and ignores it
@@ -96,15 +103,12 @@ class LocalCompileCache:
             return fn
         except KeyError:
             self.misses += 1
-            t0 = time.time()
-            fn = self._entries[key] = builder()
-            tr = current_trace()
-            if tr is not None:
-                # an actual build (not a hit) becomes a ``compile`` span
-                # on whichever job is executing on this thread
-                tr.record("compile", t0, time.time(),
-                          attrs={"kind": key[0] if isinstance(key, tuple)
-                                 and key else "plugin"})
+            # an actual build (not a hit) becomes a ``compile`` span on
+            # whichever job is executing on this thread
+            with traced("compile", step=step or "",
+                        kind=key[0] if isinstance(key, tuple) and key
+                        else "plugin"):
+                fn = self._entries[key] = builder()
             return fn
 
     def stats(self) -> dict[str, Any]:
@@ -156,17 +160,11 @@ class ShardedTransport(Transport):
     name = "sharded"
 
     def __init__(self, mesh: Mesh, donate: bool = True,
-                 compile_cache=None, cost_analysis: bool = False):
+                 compile_cache=None):
         self.mesh = mesh
         self.donate = donate
         self.compile_cache = (compile_cache if compile_cache is not None
                               else LocalCompileCache())
-        #: when True, :meth:`plugin_cost` AOT-lowers each distinct
-        #: plugin step once and serves its HLO cost analysis (FLOPs /
-        #: bytes accessed) — off by default: the extra compile is not
-        #: free and only observability consumers want it
-        self.cost_analysis = cost_analysis
-        self._costs: dict = {}
 
     def allocate(self, ds: DataSet, now: Pattern, next_: Pattern | None
                  ) -> None:
@@ -190,10 +188,28 @@ class ShardedTransport(Transport):
         """Place a host dataset onto the mesh with its pattern sharding."""
         pat = (ds.get_pattern(pattern_name) if pattern_name
                else next(iter(ds.patterns.values())))
-        arr = ds.materialise()
-        ds.backing = jax.device_put(np.asarray(arr),
-                                    self._sharding(pat, data_axis))
+        ds.backing = self._put(ds.materialise(),
+                               self._sharding(pat, data_axis))
         return ds.backing
+
+    @staticmethod
+    def _put(a, sharding: NamedSharding) -> jax.Array:
+        """``a`` placed with ``sharding``; a host array's copy is a
+        ``transfer.h2d`` span with its ``bytes``."""
+        if isinstance(a, jax.Array):
+            return jax.device_put(a, sharding)
+        a = np.asarray(a)
+        with traced("transfer.h2d", bytes=a.nbytes):
+            return jax.device_put(a, sharding)
+
+    def read(self, ds: DataSet) -> np.ndarray:
+        """The dataset on the host; a device array's copy is a
+        ``transfer.d2h`` span with its ``bytes``."""
+        out = ds.materialise()
+        if not isinstance(out, jax.Array):
+            return np.asarray(out)
+        with traced("transfer.d2h", bytes=out.nbytes):
+            return np.asarray(out)
 
     def _check_divisible(self, plugin: BasePlugin) -> None:
         """Refuse datasets whose slices cannot be laid evenly over the
@@ -210,8 +226,10 @@ class ShardedTransport(Transport):
                     f"over the {n} devices of mesh axis {da!r}")
 
     def _plugin_fn(self, plugin: BasePlugin):
-        """Traceable (consts, *arrays) -> outs.  ``consts`` is the
-        plugin's :meth:`jit_constants` dict passed as jit ARGUMENTS (not
+        """Traceable (consts, *arrays) -> outs, named after the plugin
+        (its program is ``jit_<plugin>`` on the device) and traced under
+        ``jax.named_scope(<plugin>)``.  ``consts`` is the plugin's
+        :meth:`jit_constants` dict passed as jit ARGUMENTS (not
         trace-time closure constants), so a compiled function can be
         replayed for a different plugin instance — same chain, new
         dataset — without retracing."""
@@ -250,6 +268,10 @@ class ShardedTransport(Transport):
                     else 1)
 
         def fn(consts, *arrays):
+            with jax.named_scope(plugin.name):
+                return body(consts, *arrays)
+
+        def body(consts, *arrays):
             frames = [p.to_frames(a) for p, a in zip(in_pats, arrays)]
             nf = frames[0].shape[0]
             if nf % m:
@@ -280,7 +302,7 @@ class ShardedTransport(Transport):
                 outs.append(pat.from_frames(r, shp).astype(dt))
             return tuple(outs)
 
-        return fn
+        return _named(fn, plugin.name)
 
     def _donate_mask(self, plugin: BasePlugin) -> tuple[bool, ...]:
         """Per-input donation decision: donate only at the dataset's
@@ -335,7 +357,8 @@ class ShardedTransport(Transport):
         mask = self._donate_mask(plugin)
         if lower_only:
             lconsts = plugin.jit_constants()
-            jfn = jax.jit(lambda *arrays: fn(lconsts, *arrays),
+            jfn = jax.jit(_named(lambda *arrays: fn(lconsts, *arrays),
+                                 plugin.name),
                           in_shardings=in_sh, out_shardings=out_sh,
                           donate_argnums=tuple(
                               i for i, m in enumerate(mask) if m))
@@ -357,17 +380,13 @@ class ShardedTransport(Transport):
     def _device_in(self, plugin: BasePlugin) -> list[Any]:
         self._check_divisible(plugin)
         da = plugin.driver.data_axis
-        arrays = []
-        for pd in plugin.in_data:
-            a = pd.dataset.materialise()
-            if not isinstance(a, jax.Array):
-                a = np.asarray(a)
-            # unconditional: AOT-compiled executables (persistent cache
-            # tier) are stricter than jit about input placement, so even
-            # jax.Arrays are re-committed to the pattern sharding (a
-            # no-op when already there)
-            arrays.append(jax.device_put(a, self._sharding(pd.pattern, da)))
-        return arrays
+        # unconditional: AOT-compiled executables (persistent cache
+        # tier) are stricter than jit about input placement, so even
+        # jax.Arrays are re-committed to the pattern sharding (a no-op
+        # when already there)
+        return [self._put(pd.dataset.materialise(),
+                          self._sharding(pd.pattern, da))
+                for pd in plugin.in_data]
 
     def run_plugin(self, plugin: BasePlugin) -> list[Any]:
         arrays = self._device_in(plugin)
@@ -376,7 +395,7 @@ class ShardedTransport(Transport):
             jfn = self.compile_cache.get_or_build(
                 self._plugin_key(plugin, consts),
                 lambda: self.compile_plugin(plugin, consts=consts),
-                serializable=plugin.persistable())
+                serializable=plugin.persistable(), step=plugin.name)
             outs = list(jfn(consts, *arrays))
         for pd, o in zip(plugin.out_data, outs):
             pd.dataset.backing = o
@@ -392,6 +411,7 @@ class ShardedTransport(Transport):
                       for pd in first.in_data)
         out_sh = tuple(self._sharding(pd.pattern, last.driver.data_axis)
                        for pd in last.out_data)
+        label = "+".join(p.name for p in plugins)
 
         def builder():
             fns = [self._plugin_fn(p) for p in plugins]
@@ -406,14 +426,14 @@ class ShardedTransport(Transport):
                                 for c, s in zip(cur, shs))
                 return cur
 
-            return jax.jit(chain,
+            return jax.jit(_named(chain, label),
                            in_shardings=(self._replicated(), *in_sh),
                            out_shardings=out_sh)
 
         arrays = self._device_in(first)
         key = ("fused", tuple(self._plugin_key(p) for p in plugins))
         with self.mesh:
-            jfn = self.compile_cache.get_or_build(key, builder)
+            jfn = self.compile_cache.get_or_build(key, builder, step=label)
             outs = list(jfn(tuple(p.jit_constants() for p in plugins),
                             *arrays))
         for pd, o in zip(last.out_data, outs):
@@ -450,7 +470,8 @@ class ShardedTransport(Transport):
         def builder():
             fn = self._plugin_fn(p0)
             return jax.jit(
-                lambda consts, *arrays: jax.vmap(fn)(consts, *arrays),
+                _named(lambda consts, *arrays: jax.vmap(fn)(consts, *arrays),
+                       p0.name),
                 in_shardings=(self._replicated(), *in_sh),
                 out_shardings=out_sh)
 
@@ -461,59 +482,17 @@ class ShardedTransport(Transport):
                 stack = jnp.stack(ins)          # stays on device
             else:
                 stack = np.stack([np.asarray(a) for a in ins])
-            arrays.append(jax.device_put(stack, in_sh[i]))
+            arrays.append(self._put(stack, in_sh[i]))
         consts = [p.jit_constants() for p in plugins]
         stacked_consts = jax.tree.map(
             lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *consts)
         with self.mesh:
-            jfn = self.compile_cache.get_or_build(("batch", n, k0), builder)
+            jfn = self.compile_cache.get_or_build(("batch", n, k0), builder,
+                                                  step=p0.name)
             outs = list(jfn(stacked_consts, *arrays))
         for j, p in enumerate(plugins):
             for pd, o in zip(p.out_data, outs):
                 pd.dataset.backing = o[j]
-
-    def plugin_cost(self, plugin: BasePlugin) -> dict[str, float] | None:
-        """HLO cost + memory analysis for one plugin step, from the
-        AOT-compiled program: ``flops`` / ``bytes`` (legacy alias) /
-        ``bytes_accessed`` from ``cost_analysis()``, plus
-        ``peak_memory`` / ``temp_bytes`` / ``argument_bytes`` from
-        ``memory_analysis()`` when the jax build exposes it.  None when
-        disabled or neither analysis is available.  Cached per plugin
-        key — the extra lower+compile happens once per distinct step;
-        the profiler attaches the numbers to ``process`` spans so
-        traces and ``/metrics`` can report per-plugin device profiles."""
-        if not self.cost_analysis:
-            return None
-        key = ("cost", self._plugin_key(plugin))
-        if key in self._costs:
-            return self._costs[key]
-        cost: dict[str, float] | None
-        try:
-            with self.mesh:
-                compiled = self.compile_plugin(
-                    plugin, lower_only=True).compile()
-            ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):    # older jax: per-device
-                ca = ca[0] if ca else {}
-            bytes_accessed = float(ca.get("bytes accessed", 0.0))
-            cost = {"flops": float(ca.get("flops", 0.0)),
-                    "bytes": bytes_accessed,          # legacy alias
-                    "bytes_accessed": bytes_accessed}
-            try:
-                ma = compiled.memory_analysis()
-                cost["peak_memory"] = float(
-                    getattr(ma, "temp_size_in_bytes", 0)
-                    + getattr(ma, "output_size_in_bytes", 0))
-                cost["temp_bytes"] = float(
-                    getattr(ma, "temp_size_in_bytes", 0))
-                cost["argument_bytes"] = float(
-                    getattr(ma, "argument_size_in_bytes", 0))
-            except Exception:        # noqa: BLE001 — telemetry only
-                pass                 # cost_analysis alone still useful
-        except Exception:            # noqa: BLE001 — telemetry only
-            cost = None
-        self._costs[key] = cost
-        return cost
 
     def stats(self) -> dict[str, Any]:
         return {"compile_cache": self.compile_cache.stats()}

@@ -191,13 +191,6 @@ def _build_parser() -> argparse.ArgumentParser:
                     help="--serve: spool evicted terminal-job traces "
                          "to this directory (bounded ring; "
                          "docs/observability.md)")
-    ap.add_argument("--cost-analysis",
-                    action=argparse.BooleanOptionalAction, default=False,
-                    help="sharded transport: attach per-plugin HLO "
-                         "FLOPs/bytes-accessed and peak-memory "
-                         "profiles to process spans (one extra AOT "
-                         "compile per distinct step; "
-                         "docs/observability.md)")
     return ap
 
 
@@ -210,10 +203,8 @@ def _transport_factory(args, cache: CompileCache):
         # buffer only at its FINAL use, so every dataset a checkpoint
         # (or a branching chain) still needs stays alive.
         donate = not args.batch
-        cost = getattr(args, "cost_analysis", False)
         return lambda job: ShardedTransport(mesh, donate=donate,
-                                            compile_cache=cache,
-                                            cost_analysis=cost)
+                                            compile_cache=cache)
     if args.transport == "chunked":
         return lambda job: ChunkedFileTransport()
     return lambda job: InMemoryTransport()
@@ -233,8 +224,7 @@ def _serve_main(args) -> None:
             f"http://{host}:{port}", args.workers_remote,
             transport=args.transport,
             checkpoint_dir=args.checkpoint_dir,
-            shared_fs=args.shared_fs, token=args.token,
-            cost_analysis=args.cost_analysis)
+            shared_fs=args.shared_fs, token=args.token)
         print(f"pipeline broker listening on http://{host}:{port}  "
               f"({len(workers)} local worker processes, lease_ttl="
               f"{args.lease_ttl}s; attach more with `python -m "
@@ -280,8 +270,7 @@ def _remote_demo(args) -> None:
     url = f"http://{host}:{port}"
     workers = spawn_local_workers(
         url, args.workers_remote, transport=args.transport,
-        checkpoint_dir=args.checkpoint_dir, shared_fs=args.shared_fs,
-        cost_analysis=args.cost_analysis)
+        checkpoint_dir=args.checkpoint_dir, shared_fs=args.shared_fs)
     client = PipelineClient(url)
     try:
         t0 = time.time()

@@ -10,11 +10,11 @@ from .metrics import (CATALOGUE, QUANTILES, Counter, Gauge, Histogram,
                       register_catalogue)
 from .slo import SloEngine, SloRule, default_rules, rules_from_spec
 from .trace import (Span, Trace, TraceSpool, current_trace, new_span_id,
-                    new_trace_id, render_gantt, use_trace)
+                    new_trace_id, render_gantt, traced, use_trace)
 
 __all__ = [
     "Span", "Trace", "TraceSpool", "current_trace", "use_trace",
-    "new_trace_id",
+    "traced", "new_trace_id",
     "new_span_id", "render_gantt", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "register_catalogue", "catalogue_names",
     "prometheus_name", "CATALOGUE", "QUANTILES",

@@ -296,7 +296,11 @@ CATALOGUE: tuple[tuple[str, str, str], ...] = (
     ("job.latency.queue", "histogram",
      "submit-to-dispatch queue wait, seconds"),
     ("plugin.wall", "histogram",
-     "per-plugin-step wall time across all jobs, seconds"),
+     "per-plugin-step host dispatch time across all jobs, seconds"),
+    ("transfer.h2d_bytes", "counter",
+     "bytes copied from host to device (transfer.h2d spans)"),
+    ("transfer.d2h_bytes", "counter",
+     "bytes copied from device to host (transfer.d2h spans)"),
     # -- streaming acquisition (docs/streaming.md) ----------------------
     ("stream.frames.ingested", "counter",
      "frames accepted over POST /jobs/{id}/frames"),

@@ -24,14 +24,25 @@ module is the substrate:
 
 Workers ship finished spans to the broker piggybacked on progress
 heartbeats (``take_unshipped`` / ``merge``); the "current trace" is a
-:mod:`contextvars` slot so deep layers (the compile cache) can record
-spans without threading a handle through every call.
+:mod:`contextvars` slot so deep layers (the compile cache, the
+transport, the loader) can record spans without threading a handle
+through every call (:func:`traced`).
+
+A live span (``begin``/``finish``, so also ``span()`` and the
+profiler's timers) is mirrored into JAX's profiler as a
+``jax.profiler.TraceAnnotation`` of the same name, carrying the span's
+scalar attrs, trace id, span id and parent id as stats.  While a
+profiler session runs, the program's spans then sit on the device
+trace's clock, from whichever thread opened them; with none running
+the mirror costs about a microsecond a span.  Spans recorded in
+hindsight (``record``) are not mirrored.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -67,6 +78,10 @@ class Span:
     parent_id: str | None = None
     worker_id: str | None = None
     attrs: dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: (profiler annotation, opening thread) of a live span; not on the
+    #: wire
+    _mirror: tuple | None = dataclasses.field(default=None, repr=False,
+                                              compare=False)
 
     @property
     def wall(self) -> float:
@@ -101,6 +116,27 @@ class Span:
                     parent_id=d.get("parent_id") or None,
                     worker_id=d.get("worker_id") or None,
                     attrs=dict(d.get("attrs") or {}))
+
+
+@functools.cache
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, or None where jax is missing
+    (``obs`` itself needs no jax)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def _stats(span: Span) -> dict[str, Any]:
+    """A mirrored span's profiler stats: its scalar attrs, trace id,
+    span id and parent's span id (so a reader can find its children)."""
+    out = {k: v for k, v in span.attrs.items()
+           if isinstance(v, (bool, int, float, str)) and k != "name"}
+    out.update(trace_id=span.trace_id, span_id=span.span_id,
+               parent_id=span.parent_id or "")
+    return out
 
 
 class Trace:
@@ -157,20 +193,36 @@ class Trace:
 
     def begin(self, name: str, *, worker_id: str | None = None,
               attrs: dict[str, Any] | None = None) -> Span:
-        """Open a span (parent = the thread's current innermost span)
-        and push it on the parent stack.  Close with :meth:`finish`."""
+        """Open a span (parent = the thread's current innermost span),
+        push it on the parent stack and open its profiler annotation.
+        Close with :meth:`finish`, on the same thread."""
         stack = self._stack()
         span = Span(name, time.time(),
                     parent_id=stack[-1].span_id if stack else None,
                     worker_id=worker_id or self.worker_id,
                     attrs=dict(attrs or {}))
+        annotation_type = _annotation_type()
+        if annotation_type is not None:
+            annotation = annotation_type(name)
+            annotation.__enter__()
+            span._mirror = (annotation, threading.get_ident())
         self.add(span)
         stack.append(span)
         return span
 
     def finish(self, span: Span) -> Span:
-        """Close a span opened with :meth:`begin` and pop the stack."""
+        """Close a span opened with :meth:`begin` and pop the stack.
+        The annotation takes the span's attrs as they stand now."""
         span.end = time.time()
+        if span._mirror is not None:
+            annotation, thread = span._mirror
+            assert thread == threading.get_ident(), (
+                f"span {span.name!r} finished on another thread than "
+                f"the one that began it")
+            span._mirror = None
+            if annotation.is_enabled():          # a profiler session runs
+                annotation.set_metadata(**_stats(span))
+            annotation.__exit__(None, None, None)
         stack = self._stack()
         if span in stack:
             del stack[stack.index(span):]
@@ -264,6 +316,18 @@ def use_trace(trace: Trace | None):
         yield trace
     finally:
         _current.reset(token)
+
+
+@contextlib.contextmanager
+def traced(name: str, **attrs: Any):
+    """``current_trace().span(name, **attrs)``; yields None and records
+    nothing when no trace is current."""
+    trace = _current.get()
+    if trace is None:
+        yield None
+        return
+    with trace.span(name, **attrs) as span:
+        yield span
 
 
 # -- retention ----------------------------------------------------------

@@ -41,7 +41,7 @@ import threading
 import time
 from typing import Any, Callable
 
-from ..obs.trace import current_trace
+from ..obs.trace import current_trace, traced
 
 #: on-disk payload framing: magic + one JSON header line + pickle body
 _MAGIC = b"SAVUEXE1\n"
@@ -411,7 +411,7 @@ class CompileCache:
         self.uploads = 0                 # payloads handed to ``publish``
 
     def get_or_build(self, key, builder: Callable[[], Any],
-                     serializable: bool = False):
+                     serializable: bool = False, step: str | None = None):
         """Return the cached value for ``key``, building it (once) on a
         miss.
 
@@ -426,6 +426,9 @@ class CompileCache:
                 memory miss the persistent tier is consulted first
                 (disk, then the ``fetch`` callback), and a fresh build
                 is serialized back out (disk + ``publish``).
+            step: the plugin step (or fused/batched group) the program
+                runs, named on the ``compile`` and
+                ``executable.deserialize`` spans of a miss.
 
         Returns: the cached/built value.  A ``builder`` that raises
         propagates to its caller; waiting losers retry (and one of them
@@ -451,19 +454,16 @@ class CompileCache:
             sig = None
             if serializable and self.store is not None:
                 sig = executable_signature(key)
-                fn = self._load_persisted(sig)
+                fn = self._load_persisted(sig, step or "")
             if fn is None:
                 t0 = time.perf_counter()
-                t0_epoch = time.time()
-                fn = builder()
+                # actual builds (never hits) show up as ``compile`` spans
+                # on whichever job triggered them
+                with traced("compile", step=step or "",
+                            kind=key[0] if isinstance(key, tuple) and key
+                            else "plugin"):
+                    fn = builder()
                 dt = time.perf_counter() - t0
-                tr = current_trace()
-                if tr is not None:
-                    # actual builds (never hits) show up as ``compile``
-                    # spans on whichever job triggered them
-                    tr.record("compile", t0_epoch, t0_epoch + dt,
-                              attrs={"kind": key[0] if isinstance(key, tuple)
-                                     and key else "plugin"})
                 with self._lock:
                     self.build_s += dt
                 if sig is not None:
@@ -489,12 +489,13 @@ class CompileCache:
                 self._building.pop(key).set()
 
     # -- persistent tier ------------------------------------------------
-    def _load_persisted(self, sig: str):
+    def _load_persisted(self, sig: str, step: str):
         """A runnable executable for ``sig`` from the persistent tier —
         local disk first, then the broker ``fetch`` callback — or None
         (count a disk miss; the caller compiles).  Loads record
-        ``executable.fetch`` + ``executable.deserialize`` spans on the
-        current trace, mirroring how real builds record ``compile``."""
+        ``executable.fetch`` + ``executable.deserialize`` spans (the
+        latter live, naming ``step``) on the current trace, as real
+        builds record ``compile``."""
         tr = current_trace()
         t0 = time.time()
         payload = self.store.get_bytes(sig)
@@ -515,9 +516,9 @@ class CompileCache:
             tr.record("executable.fetch", t0, time.time(),
                       attrs={"sig": sig[:16], "source": source,
                              "bytes": len(payload)})
-        t1 = time.time()
         try:
-            fn = deserialize_payload(payload, sig)
+            with traced("executable.deserialize", sig=sig[:16], step=step):
+                fn = deserialize_payload(payload, sig)
         except StaleExecutable:
             # never silently loaded: corrupt/version-mismatched payloads
             # are dropped from disk and the caller compiles fresh
@@ -526,9 +527,6 @@ class CompileCache:
                 self.disk_misses += 1
             self.store.discard(sig)
             return None
-        if tr is not None:
-            tr.record("executable.deserialize", t1, time.time(),
-                      attrs={"sig": sig[:16]})
         with self._lock:
             self.disk_hits += 1
         return fn

@@ -102,24 +102,29 @@ def _observe_terminal(metrics: MetricsRegistry | None, job: Job,
             job.finished_at - job.submitted_at)
 
 
-def _observe_plugin_spans(metrics: MetricsRegistry | None,
-                          spans) -> None:
-    """Feed ``process``-phase plugin spans into the plugin-wall
-    histograms (the aggregate plus one per plugin name).  Callers pass
-    only spans seen for the FIRST time (a fresh run, or the newly-merged
-    slice of a heartbeat) so nothing double-counts."""
+def observe_spans(metrics: MetricsRegistry | None, spans) -> None:
+    """Feed finished spans into the registry: ``process``-phase plugin
+    spans into the plugin-wall histograms (the aggregate plus one per
+    plugin name; host dispatch time, not device time), transfer spans'
+    ``bytes`` into the transfer counters.  Callers pass only spans seen
+    for the FIRST time (a fresh run, the newly-merged slice of a
+    heartbeat, one result fetch) so nothing double-counts."""
     if metrics is None:
         return
     for s in spans:
-        if not s.name.startswith("plugin.") or s.end is None:
+        if s.end is None:
             continue
-        if s.attrs.get("phase") != "process":
-            continue
-        metrics.histogram("plugin.wall").observe(s.wall)
-        plugin = s.attrs.get("plugin") or s.name
-        metrics.histogram(f"plugin.wall.{plugin}").observe(s.wall)
-        if s.attrs.get("flops"):
-            metrics.gauge(f"plugin.flops.{plugin}").set(s.attrs["flops"])
+        if s.name == "transfer.h2d":
+            metrics.counter("transfer.h2d_bytes").inc(
+                s.attrs.get("bytes", 0))
+        elif s.name == "transfer.d2h":
+            metrics.counter("transfer.d2h_bytes").inc(
+                s.attrs.get("bytes", 0))
+        elif (s.name.startswith("plugin.")
+              and s.attrs.get("phase") == "process"):
+            metrics.histogram("plugin.wall").observe(s.wall)
+            plugin = s.attrs.get("plugin") or s.name
+            metrics.histogram(f"plugin.wall.{plugin}").observe(s.wall)
 
 
 class PipelineScheduler:
@@ -346,7 +351,9 @@ class PipelineScheduler:
         job.state = JobState.CHECKING
         self._dispatched(job)
         try:
-            with use_trace(job.trace):
+            # job.run: pickup to the last dispatch (a jitted step returns
+            # before the device finishes)
+            with use_trace(job.trace), job.trace.span("job.run"):
                 self._resolve_upstream(job)
                 runner = PluginRunner(job.process_list,
                                       self.transport_factory(job),
@@ -437,18 +444,27 @@ class PipelineScheduler:
         runners: list[PluginRunner] = []
         live: list[Job] = []
         resumed: list[Job] = []
+        #: job id -> its open job.run span, closed as the job finishes
+        runs = {}
+
+        def finish(done: list[Job]) -> None:
+            for job in done:
+                job.trace.finish(runs.pop(job.job_id))
+            self._finish(done)
+
         for job in jobs:
             job.started_at = time.time()
             job.state = JobState.CHECKING
             self._dispatched(job)
+            runs[job.job_id] = job.trace.begin("job.run")
             try:
                 with use_trace(job.trace):
                     self._resolve_upstream(job)
-                r = PluginRunner(job.process_list, transport,
-                                 profiler=Profiler(trace=job.trace),
-                                 fuse=self.fuse)
-                job.runner = r
-                r.prepare()
+                    r = PluginRunner(job.process_list, transport,
+                                     profiler=Profiler(trace=job.trace),
+                                     fuse=self.fuse)
+                    job.runner = r
+                    r.prepare()
                 if self.checkpoints is not None:
                     with job.trace.span("checkpoint.restore"):
                         job.resumed_from = self.checkpoints.restore(
@@ -461,28 +477,30 @@ class PipelineScheduler:
                     live.append(job)
             except UpstreamGone as e:
                 self._cancel_evicted(job, e)
-                self._finish([job])
+                finish([job])
             except Exception as e:
                 self._fail(job, e)
-                self._finish([job])
+                finish([job])
         for job in resumed:
             try:
-                self._drive(job, job.runner)
+                with use_trace(job.trace):
+                    self._drive(job, job.runner)
             except Exception as e:
                 self._fail(job, e)
             finally:
-                self._finish([job])
+                finish([job])
         jobs = live
         if not jobs:
             return
         if len(jobs) == 1:
             job = jobs[0]
             try:
-                self._drive(job, job.runner)
+                with use_trace(job.trace):
+                    self._drive(job, job.runner)
             except Exception as e:
                 self._fail(job, e)
             finally:
-                self._finish([job])
+                finish([job])
             return
         try:
             for job in jobs:
@@ -493,16 +511,22 @@ class PipelineScheduler:
                 t0 = time.time()
                 if can_batch and len(groups[0]) == 1:
                     try:
-                        transport.run_plugin_batch([g[0] for g in groups])
+                        # the gang's shared transfers and compiles are
+                        # recorded on its first job's trace
+                        with use_trace(jobs[0].trace):
+                            transport.run_plugin_batch(
+                                [g[0] for g in groups])
                     except ValueError:       # signature mismatch: solo
-                        for g in groups:
-                            transport.run_plugin(g[0])
+                        for job, g in zip(jobs, groups):
+                            with use_trace(job.trace):
+                                transport.run_plugin(g[0])
                 else:
-                    for g in groups:
-                        if len(g) > 1:
-                            transport.run_fused(g)
-                        else:
-                            transport.run_plugin(g[0])
+                    for job, g in zip(jobs, groups):
+                        with use_trace(job.trace):
+                            if len(g) > 1:
+                                transport.run_fused(g)
+                            else:
+                                transport.run_plugin(g[0])
                 t1 = time.time()
                 for job, r, g in zip(jobs, runners, groups):
                     # the batched call is one compiled program over the
@@ -531,7 +555,7 @@ class PipelineScheduler:
                     job.metadata["traceback"] = tb
                     job.state = JobState.FAILED
         finally:
-            self._finish(jobs)
+            finish(jobs)
 
     def _finish(self, jobs: list[Job]) -> None:
         now = time.time()
@@ -547,7 +571,7 @@ class PipelineScheduler:
             # _finish sees each job exactly once — safe to fold the
             # whole trace into the plugin-wall histograms here
             _observe_terminal(self.metrics, job, self.events)
-            _observe_plugin_spans(self.metrics, job.trace.spans())
+            observe_spans(self.metrics, job.trace.spans())
         for job in jobs:
             # per-job so the queue can propagate DONE/FAILED/CANCELLED
             # into each job's downstream cone (docs/workflows.md)
@@ -1056,7 +1080,7 @@ class WorkerBroker:
         # idempotent), and the killed-worker spans the resume timeline
         # needs arrive exactly this way
         new_spans = job.trace.merge(body.get("spans") or [])
-        _observe_plugin_spans(self.metrics, new_spans)
+        observe_spans(self.metrics, new_spans)
         with self._lock:
             if worker_id in self._workers:
                 self._check_secret_locked(worker_id,
@@ -1260,7 +1284,7 @@ class WorkerBroker:
         # below raises LeaseLost — a late completion is void as an
         # OUTCOME, but its spans are real history on the timeline
         new_spans = job.trace.merge(body.get("spans") or [])
-        _observe_plugin_spans(self.metrics, new_spans)
+        observe_spans(self.metrics, new_spans)
         results = body.get("results") or {}
         if not isinstance(results, dict):
             raise WireError("results must be an object")

@@ -87,19 +87,21 @@ from urllib.parse import parse_qs, unquote, urlparse
 
 import numpy as np
 
+import jax
+
 from ..core.process_list import ProcessListError
 from ..core.transport import ChunkedFile, Transport
 from ..obs.export import trace_to_otlp
 from ..obs.log import EventLog
 from ..obs.metrics import MetricsRegistry, register_catalogue
 from ..obs.slo import SloEngine
-from ..obs.trace import Span, TraceSpool, render_gantt
+from ..obs.trace import Span, TraceSpool, render_gantt, use_trace
 from .checkpoint import CheckpointStore
 from .compile_cache import CompileCache
 from .job import Job, JobState
 from .queue import JobQueue, QueueFull
 from .scheduler import (LeaseLost, PipelineScheduler, WorkerAuthError,
-                        WorkerBroker)
+                        WorkerBroker, observe_spans)
 from .sweep import SweepError, SweepGroup, SweepManager
 from .wire import WireError, from_spec, registry_spec
 from .workflow import WorkflowError, WorkflowGroup, WorkflowManager
@@ -1228,36 +1230,56 @@ class _PipelineHandler(BaseHTTPRequestHandler):
 
     # -- result streaming -----------------------------------------------
     def _send_result(self, job_id: str, dataset: str | None) -> None:
+        svc = self.service
         try:
-            remote = self.service.result_file(job_id, dataset)
+            remote = svc.result_file(job_id, dataset)
             if remote is not None:        # broker mode: stream the file
                 return self._send_result_file(remote[1], remote[0])
-            ds, transport = self.service.result_dataset(job_id, dataset)
+            ds, transport = svc.result_dataset(job_id, dataset)
+            trace = svc.queue.job(job_id).trace
         except KeyError as e:
             return self._error(404, str(e))
         except RuntimeError as e:
             return self._error(409, str(e))
+        with use_trace(trace), trace.span("result.fetch",
+                                          dataset=ds.name) as fetch:
+            self._stream_result(ds, transport, trace)
+        observe_spans(svc.metrics, [s for s in trace.spans()
+                                    if s.parent_id == fetch.span_id])
+
+    def _stream_result(self, ds, transport: Transport, trace) -> None:
+        """The result as ``.npy``: the device's tail of the job
+        (``result.device_wait``), the copy to the host (the transport's
+        ``transfer.d2h``), then the header and bytes (``result.send``)."""
         header = _npy_header(ds.shape, ds.dtype)
+        backing = ds.backing
+        if isinstance(backing, ChunkedFile):
+            with trace.span("result.send"):
+                self._send_npy_head(header, ds)
+                # chunk-row slabs straight off the checkpoint-layer file
+                # format: O(slab) RAM however big the volume is
+                backing.flush()
+                step = backing.chunks[0]
+                rest = tuple(slice(0, s) for s in ds.shape[1:])
+                for i in range(0, ds.shape[0], step):
+                    slab = backing.read(
+                        (slice(i, min(i + step, ds.shape[0])),) + rest)
+                    self.wfile.write(np.ascontiguousarray(slab).tobytes())
+            return
+        with trace.span("result.device_wait"):
+            jax.block_until_ready(backing)
+        arr = np.ascontiguousarray(np.asarray(transport.read(ds)))
+        with trace.span("result.send"):
+            self._send_npy_head(header, ds)
+            self.wfile.write(arr.tobytes())
+
+    def _send_npy_head(self, header: bytes, ds) -> None:
         self.send_response(200)
         self.send_header("Content-Type", "application/x-npy")
         self.send_header("Content-Length", str(len(header) + ds.nbytes))
         self.send_header("X-Dataset", ds.name)
         self.end_headers()
         self.wfile.write(header)
-        backing = ds.backing
-        if isinstance(backing, ChunkedFile):
-            # chunk-row slabs straight off the checkpoint-layer file
-            # format: O(slab) RAM however big the volume is
-            backing.flush()
-            step = backing.chunks[0]
-            rest = tuple(slice(0, s) for s in ds.shape[1:])
-            for i in range(0, ds.shape[0], step):
-                slab = backing.read(
-                    (slice(i, min(i + step, ds.shape[0])),) + rest)
-                self.wfile.write(np.ascontiguousarray(slab).tobytes())
-        else:
-            arr = np.ascontiguousarray(np.asarray(transport.read(ds)))
-            self.wfile.write(arr.tobytes())
 
     def _send_sweep_result(self, sweep_id: str,
                            dataset: str | None) -> None:

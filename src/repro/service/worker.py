@@ -801,7 +801,6 @@ def spawn_local_workers(url: str, n: int, *, transport: str = "inmemory",
                         pythonpath_extra: tuple[str, ...] = (),
                         token: str | None = None,
                         executables_dir: str | None = None,
-                        cost_analysis: bool = False,
                         stdout: Any = None) -> list:
     """Spawn ``n`` worker subprocesses against a broker URL — the
     ``pipeline_serve --workers-remote N`` demo, benchmarks and tests all
@@ -843,16 +842,13 @@ def spawn_local_workers(url: str, n: int, *, transport: str = "inmemory",
             cmd += ["--token", token]
         if executables_dir is not None:
             cmd += ["--executables-dir", executables_dir]
-        if cost_analysis:
-            cmd += ["--cost-analysis"]
         procs.append(subprocess.Popen(cmd, env=env, stdout=stdout,
                                       stderr=stdout))
     return procs
 
 
 def _transport_factory(kind: str, scratch: str, donate: bool = True,
-                       compile_cache: CompileCache | None = None,
-                       cost_analysis: bool = False
+                       compile_cache: CompileCache | None = None
                        ) -> Callable[[dict], Transport]:
     if kind == "sharded":
         import jax
@@ -864,8 +860,7 @@ def _transport_factory(kind: str, scratch: str, donate: bool = True,
         cache = (compile_cache if compile_cache is not None
                  else CompileCache())
         return lambda desc: ShardedTransport(mesh, donate=donate,
-                                             compile_cache=cache,
-                                             cost_analysis=cost_analysis)
+                                             compile_cache=cache)
     if kind == "chunked":
         return lambda desc: ChunkedFileTransport(
             os.path.join(scratch, desc["job_id"]))
@@ -915,11 +910,6 @@ def main(argv: list[str] | None = None) -> None:
                     help="local disk tier for serialized executables "
                          "(sharded transport only; default: "
                          "'executables' under the compile cache root)")
-    ap.add_argument("--cost-analysis",
-                    action=argparse.BooleanOptionalAction, default=False,
-                    help="attach XLA cost/memory analysis (flops, bytes "
-                         "accessed, peak memory) to every jitted "
-                         "plugin's process span (sharded transport)")
     args = ap.parse_args(argv)
     for mod in args.imports:
         importlib.import_module(mod)
@@ -936,8 +926,7 @@ def main(argv: list[str] | None = None) -> None:
         # --batch rule), so donate only when leases stay solo
         transport_factory=_transport_factory(
             args.transport, scratch, donate=args.max_batch == 1,
-            compile_cache=compile_cache,
-            cost_analysis=args.cost_analysis),
+            compile_cache=compile_cache),
         checkpoint_dir=args.checkpoint_dir, shared_fs=args.shared_fs,
         worker_id=args.worker_id, max_batch=args.max_batch,
         sweeps=args.sweeps, poll=args.poll, heartbeat=args.heartbeat,

@@ -15,6 +15,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import traced
 from .geometry import ParallelGeometry
 
 # (value, a, b, x0, y0, phi_deg) — standard Shepp-Logan ellipses
@@ -166,10 +167,14 @@ def _raw_scan(proj, row_scale, *, noise: float, seed: int, mu: float,
                      jnp.asarray(dark, jnp.float32),
                      jnp.asarray(flat, jnp.float32), mu)
     if noise > 0:
-        counts = rng.poisson(np.asarray(counts) / noise) * noise
+        with traced("transfer.d2h", bytes=counts.nbytes):
+            host = np.asarray(counts)
+        counts = rng.poisson(host / noise) * noise
         data = np.clip(counts, 0, 65535).astype(np.uint16)
     else:
-        data = np.asarray(counts.astype(jnp.uint16))
+        counts = counts.astype(jnp.uint16)
+        with traced("transfer.d2h", bytes=counts.nbytes):
+            data = np.asarray(counts)
     return {
         "data": data,
         "dark": np.clip(dark, 0, 65535).astype(np.uint16),
@@ -203,5 +208,6 @@ def simulate_phantom_scan(geom: ParallelGeometry, *, noise: float = 0.0,
     proj = shepp_logan_sinogram(n, geom)[:, None, :]      # on the device
     scan = _raw_scan(proj, row_scales(geom.n_rows), noise=noise, seed=seed,
                      mu=mu)
-    scan["truth"] = phantom_stack(n, geom.n_rows)
+    with traced("loader.truth"):
+        scan["truth"] = phantom_stack(n, geom.n_rows)
     return scan

@@ -263,3 +263,35 @@ def test_spec_submission_equals_processlist_submission(service):
     s1, s2 = (client.wait(j, timeout=300) for j in (j1, j2))
     assert s1["state"] == s2["state"] == "done"
     np.testing.assert_array_equal(client.result(j1), client.result(j2))
+
+
+def test_result_fetch_is_traced_with_its_transfer(service):
+    """A result fetch lands on the job's trace as ``result.fetch`` with
+    three disjoint children: the device wait, the copy to the host and
+    the send; the copy's bytes reach ``transfer.d2h_bytes``."""
+    svc, client = service
+    jid = client.submit(_chain(seed=3))
+    assert client.wait(jid, timeout=300)["state"] == "done"
+    before = svc.metrics.counter("transfer.d2h_bytes").value
+    vol = client.result(jid)
+    spans = client.trace(jid)["spans"]
+    (fetch,) = [s for s in spans if s["name"] == "result.fetch"]
+    children = sorted((s for s in spans
+                       if s.get("parent_id") == fetch["span_id"]),
+                      key=lambda s: s["start"])
+    assert [s["name"] for s in children] == [
+        "result.device_wait", "transfer.d2h", "result.send"]
+    for a, b in zip(children, children[1:]):
+        assert a["end"] <= b["start"]
+    assert fetch["start"] <= children[0]["start"]
+    assert children[-1]["end"] <= fetch["end"]
+    assert children[1]["attrs"]["bytes"] == vol.nbytes
+    assert svc.metrics.counter("transfer.d2h_bytes").value - before \
+        == vol.nbytes
+    assert "transfer_d2h_bytes" in client.metrics()
+    # the run itself: job.run is the parent of the plugin spans, and the
+    # raw scan's copy to the device was counted
+    (run,) = [s for s in spans if s["name"] == "job.run"]
+    plugin = [s for s in spans if s["name"].startswith("plugin.")]
+    assert plugin and all(s["parent_id"] == run["span_id"] for s in plugin)
+    assert svc.metrics.counter("transfer.h2d_bytes").value > 0

@@ -65,6 +65,88 @@ def test_record_defaults_parent_to_open_span():
     assert compile_span.parent_id == p.span_id
 
 
+def _profiled(tmp_path, work) -> list:
+    """Run ``work()`` inside a ``jax.profiler`` session; return the
+    host plane's events as (name, {stat: value})."""
+    import glob
+
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    return [(ev.name, dict(ev.stats)) for plane in data.planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+
+
+def test_span_is_mirrored_on_the_profiler_from_any_thread(tmp_path):
+    tr = Trace("job-7")
+
+    def open_close():
+        with tr.span("x", bytes=3):
+            pass
+
+    def on_a_worker_thread():
+        t = threading.Thread(target=open_close)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+
+    events = _profiled(tmp_path, on_a_worker_thread)
+    (stats,) = [st for name, st in events if name == "x"]
+    (span,) = tr.spans()
+    assert stats["bytes"] == 3 and stats["trace_id"] == "job-7"
+    assert stats["span_id"] == span.span_id
+    assert span.end is not None and span.attrs == {"bytes": 3}
+
+
+def test_span_works_without_a_profiler_session():
+    tr = Trace("job-8")
+    with tr.span("outer", phase="process") as outer:
+        with tr.span("inner"):
+            pass
+    assert [s.name for s in tr.spans()] == ["outer", "inner"]
+    assert all(s.end is not None and s._mirror is None
+               for s in tr.spans())
+    assert outer.to_wire()["attrs"] == {"phase": "process"}
+
+
+def test_hindsight_spans_are_not_mirrored(tmp_path):
+    tr = Trace("job-9")
+
+    def work():
+        tr.record("queue.wait", time.time() - 1, time.time())
+        with tr.span("live"):
+            pass
+
+    names = [name for name, _ in _profiled(tmp_path, work)]
+    assert "live" in names and "queue.wait" not in names
+
+
+def test_mirrored_span_must_finish_on_its_own_thread():
+    tr = Trace()
+    span = tr.begin("job.run")
+    failed = []
+
+    def finish_elsewhere():
+        try:
+            tr.finish(span)
+        except AssertionError:
+            failed.append(True)
+
+    t = threading.Thread(target=finish_elsewhere)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive() and failed == [True]
+    tr.finish(span)
+    assert span.end is not None
+
+
 def test_merge_dedups_on_span_id_and_returns_only_new():
     tr = Trace("job-1")
     wire = [Span("lease", 1.0, 2.0, span_id="aaa").to_wire(),
@@ -279,7 +361,7 @@ def test_catalogue_registers_every_name():
 # ==================================================== completeness guard
 #: per-plugin metrics minted from plugin names at runtime — the only
 #: names allowed to live outside the CATALOGUE
-DYNAMIC_METRIC_PREFIXES = ("plugin.wall.", "plugin.flops.")
+DYNAMIC_METRIC_PREFIXES = ("plugin.wall.",)
 _METRIC_CALL_RE = re.compile(
     r"""\.(counter|gauge|histogram)\(\s*["']([^"']+)["']""")
 
