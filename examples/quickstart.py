@@ -7,7 +7,7 @@ sinogram filter → FBP → saver.
 import numpy as np
 
 from repro.core import InMemoryTransport, PluginRunner
-from repro.tomo import standard_chain
+from repro.tomo import phantom_truth, standard_chain
 
 
 def main():
@@ -16,8 +16,7 @@ def main():
     datasets = runner.run()
 
     recon = np.asarray(datasets["recon"].materialise())
-    truth = next(d.metadata["truth"] for d in runner.lineage
-                 if d.metadata.get("truth") is not None)
+    truth = phantom_truth(runner.lineage[0].metadata["geometry"])
     sl = slice(8, -8)
     corr = np.corrcoef(truth[:, sl, sl].ravel(),
                        recon[:, sl, sl].ravel())[0, 1]

@@ -1,17 +1,17 @@
 # Tomography substrate: the paper's own domain (full-field parallel-beam
 # CT) — geometry, synthetic scans, and the standard processing plugins.
 from .geometry import ParallelGeometry
-from .phantom import (forward_project, phantom_stack, shepp_logan,
-                      simulate_raw_scan)
+from .phantom import (forward_project, phantom_stack, phantom_truth,
+                      shepp_logan, simulate_raw_scan)
 from .plugins import (DarkFlatCorrection, Downsample, FBPRecon,
                       HDF5LikeSaver, PaganinFilter, Quantify, RingRemoval,
                       SinogramFilter, SyntheticTomoLoader, UpstreamLoader)
 
 __all__ = [
-    "ParallelGeometry", "shepp_logan", "phantom_stack", "forward_project",
-    "simulate_raw_scan", "SyntheticTomoLoader", "DarkFlatCorrection",
-    "PaganinFilter", "RingRemoval", "SinogramFilter", "FBPRecon",
-    "HDF5LikeSaver", "UpstreamLoader", "Downsample", "Quantify",
+    "ParallelGeometry", "shepp_logan", "phantom_stack", "phantom_truth",
+    "forward_project", "simulate_raw_scan", "SyntheticTomoLoader",
+    "DarkFlatCorrection", "PaganinFilter", "RingRemoval", "SinogramFilter",
+    "FBPRecon", "HDF5LikeSaver", "UpstreamLoader", "Downsample", "Quantify",
 ]
 
 

@@ -200,14 +200,19 @@ def simulate_raw_scan(volume: np.ndarray, geom: ParallelGeometry, *,
 def simulate_phantom_scan(geom: ParallelGeometry, *, noise: float = 0.0,
                           seed: int = 0, mu: float = 0.02
                           ) -> dict[str, np.ndarray]:
-    """:func:`simulate_raw_scan` of ``phantom_stack(n_det, n_rows)``,
-    built from the closed-form sinogram of one slice (rows are scaled
-    copies), so a beamline-size scan costs O(angles·rows·n_det) on the
-    device instead of a projector pass per row."""
-    n = geom.n_det
-    proj = shepp_logan_sinogram(n, geom)[:, None, :]      # on the device
-    scan = _raw_scan(proj, row_scales(geom.n_rows), noise=noise, seed=seed,
+    """:func:`simulate_raw_scan` of :func:`phantom_truth`, without the
+    truth volume, built from the closed-form sinogram of one slice (rows
+    are scaled copies), so a beamline-size scan costs
+    O(angles·rows·n_det) on the device instead of a projector pass per
+    row.  A caller that compares against the phantom builds it with
+    :func:`phantom_truth`."""
+    proj = shepp_logan_sinogram(geom.n_det, geom)[:, None, :]  # on device
+    return _raw_scan(proj, row_scales(geom.n_rows), noise=noise, seed=seed,
                      mu=mu)
+
+
+def phantom_truth(geom: ParallelGeometry) -> np.ndarray:
+    """The (n_rows, n_det, n_det) volume :func:`simulate_phantom_scan`
+    projects, rasterised on the host on demand."""
     with traced("loader.truth"):
-        scan["truth"] = phantom_stack(n, geom.n_rows)
-    return scan
+        return phantom_stack(geom.n_det, geom.n_rows)

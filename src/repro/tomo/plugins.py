@@ -29,7 +29,10 @@ from .phantom import simulate_phantom_scan
 # ----------------------------------------------------------------------
 class SyntheticTomoLoader(BaseLoader):
     """Creates a raw full-field scan (θ, y, x) from a phantom — the
-    nx_tomo_loader analogue, with dark/flat fields in metadata."""
+    nx_tomo_loader analogue, with dark/flat fields in metadata.  A given
+    ``scan`` that carries a ``truth`` volume passes it on as metadata;
+    the synthetic phantom's truth is :func:`phantom_truth` of the
+    ``geometry`` metadata."""
 
     name = "synthetic_tomo_loader"
     parameters = {"n_det": 64, "n_angles": 64, "n_rows": 4, "noise": 0.0,
@@ -58,8 +61,9 @@ class SyntheticTomoLoader(BaseLoader):
         ds.metadata.update({
             "dark": scan["dark"], "flat": scan["flat"],
             "mu": scan.get("mu", 1.0), "geometry": geom,
-            "truth": scan.get("truth"),
         })
+        if scan.get("truth") is not None:
+            ds.metadata["truth"] = scan["truth"]
         return [ds]
 
 

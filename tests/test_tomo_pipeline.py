@@ -5,15 +5,18 @@ import pytest
 
 from repro.core import ChunkedFileTransport, InMemoryTransport, PluginRunner
 from repro.tomo import (ParallelGeometry, forward_project, phantom_stack,
-                        shepp_logan, simulate_raw_scan, standard_chain)
+                        phantom_truth, shepp_logan, simulate_raw_scan,
+                        standard_chain)
 
 
 def _run(chain, transport=None):
     runner = PluginRunner(chain, transport or InMemoryTransport())
     out = runner.run()
     recon = np.asarray(runner.transport.read(out["recon"]))
-    truth = next(d.metadata["truth"] for d in runner.lineage
-                 if d.metadata.get("truth") is not None)
+    loaded = runner.lineage[0].metadata
+    truth = loaded.get("truth")
+    if truth is None:
+        truth = phantom_truth(loaded["geometry"])
     return recon, truth, runner
 
 

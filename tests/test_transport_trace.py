@@ -1,5 +1,6 @@
 """ShardedTransport's host<->device transfer spans and the names of its
-step programs, on a one-device CPU mesh."""
+step programs, on a one-device CPU mesh; the served loader builds no
+phantom truth."""
 import numpy as np
 
 import jax
@@ -10,8 +11,10 @@ from repro.core import PluginRunner, ProcessList, ShardedTransport
 from repro.obs import Trace, use_trace
 from repro.tomo import (DarkFlatCorrection, HDF5LikeSaver, RingRemoval,
                         SyntheticTomoLoader)
+from repro.tomo import phantom
 from repro.tomo.geometry import ParallelGeometry
-from repro.tomo.phantom import simulate_phantom_scan
+from repro.tomo.phantom import (phantom_stack, phantom_truth,
+                                simulate_phantom_scan)
 
 N_ANGLES, N_ROWS, N_DET = 12, 2, 16
 
@@ -56,7 +59,49 @@ def test_chain_copies_the_raw_scan_to_the_device_once():
     # the loader made the counts on the device and copied them back
     (d2h,) = _spans(trace, "transfer.d2h")
     assert d2h.attrs["bytes"] == raw_bytes
-    assert len(_spans(trace, "loader.truth")) == 1
+    # no step reads the phantom's truth, so the served chain builds none
+    assert _spans(trace, "loader.truth") == []
+
+
+def test_served_chain_never_builds_the_phantom_truth(monkeypatch):
+    def refuse(*args, **kwargs):
+        raise AssertionError("the served chain built the phantom truth")
+
+    monkeypatch.setattr(phantom, "phantom_stack", refuse)
+    _, runner = _run(_chain())
+    assert runner.transport.read(runner.datasets["tomo"]).shape == (
+        N_ANGLES, N_ROWS, N_DET)
+
+
+def test_loader_metadata_is_the_simulated_scan_s():
+    geom = ParallelGeometry(N_ANGLES, N_DET, N_ROWS)
+    scan = simulate_phantom_scan(geom)
+    (ds,) = SyntheticTomoLoader(n_det=N_DET, n_angles=N_ANGLES,
+                                n_rows=N_ROWS, out_datasets=("tomo",)).load()
+    assert np.array_equal(ds.materialise(), scan["data"])
+    assert np.array_equal(ds.metadata["dark"], scan["dark"])
+    assert np.array_equal(ds.metadata["flat"], scan["flat"])
+    assert ds.metadata["mu"] == scan["mu"]
+    assert ds.metadata["geometry"] == geom
+    assert "truth" not in ds.metadata
+
+
+def test_loader_passes_on_a_given_scan_s_truth():
+    scan = simulate_phantom_scan(ParallelGeometry(N_ANGLES, N_DET, N_ROWS))
+    scan["truth"] = np.ones((N_ROWS, N_DET, N_DET), np.float32)
+    (ds,) = SyntheticTomoLoader(scan=scan, out_datasets=("tomo",)).load()
+    assert ds.metadata["truth"] is scan["truth"]
+
+
+def test_phantom_truth_is_the_phantom_stack_in_one_span():
+    geom = ParallelGeometry(N_ANGLES, N_DET, N_ROWS)
+    trace = Trace()
+    with use_trace(trace):
+        truth = phantom_truth(geom)
+    assert np.array_equal(truth, phantom_stack(N_DET, N_ROWS))
+    assert truth.dtype == np.float32
+    (span,) = _spans(trace, "loader.truth")
+    assert span.end is not None
 
 
 def test_input_already_on_the_device_records_no_transfer():
