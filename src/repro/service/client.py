@@ -14,6 +14,8 @@ message (:class:`ServiceError.status` / ``.message``).
 """
 from __future__ import annotations
 
+import contextlib
+import http.client
 import io
 import json
 import time
@@ -23,6 +25,7 @@ from typing import Any
 from urllib.parse import quote
 
 import numpy as np
+from numpy.lib import format as npy_format
 
 from ..core.process_list import ProcessList
 from .wire import to_spec
@@ -31,7 +34,8 @@ _TERMINAL = ("done", "failed", "cancelled")
 
 
 class ServiceError(RuntimeError):
-    """An HTTP endpoint answered with an error status.
+    """An HTTP endpoint answered with an error status, or with a result
+    body that was not whole (``status`` is then the response's own).
 
     Attributes:
         status: the HTTP status code (400 validation, 404 unknown,
@@ -81,15 +85,25 @@ class PipelineClient:
         else:
             data = None if body is None else json.dumps(body).encode()
             hdrs = {"Content-Type": "application/json"} if data else {}
-        if self.token is not None:
-            hdrs["Authorization"] = f"Bearer {self.token}"
         hdrs.update(headers or {})
+        with self._open(method, path, data, hdrs) as resp:
+            payload = resp.read()
+            resp_headers = dict(resp.headers)
+        out = payload if raw else json.loads(payload)
+        return (out, resp_headers) if with_headers else out
+
+    @contextlib.contextmanager
+    def _open(self, method: str, path: str, data: bytes | None = None,
+              hdrs: dict[str, str] | None = None):
+        """The open response of one request; an error status raises
+        :class:`ServiceError` with the server's ``error`` message."""
+        hdrs = dict(hdrs or {})
+        if self.token is not None:
+            hdrs.setdefault("Authorization", f"Bearer {self.token}")
         req = urllib.request.Request(
             self.base_url + path, data=data, method=method, headers=hdrs)
         try:
-            with urllib.request.urlopen(req, timeout=self.timeout) as resp:
-                payload = resp.read()
-                resp_headers = dict(resp.headers)
+            resp = urllib.request.urlopen(req, timeout=self.timeout)
         except urllib.error.HTTPError as e:
             raw = e.read()
             parsed: dict[str, Any] | None = None
@@ -100,8 +114,8 @@ class PipelineClient:
                 message = raw.decode(errors="replace") or e.reason
                 parsed = parsed if isinstance(parsed, dict) else None
             raise ServiceError(e.code, message, detail=parsed) from None
-        out = payload if raw else json.loads(payload)
-        return (out, resp_headers) if with_headers else out
+        with resp:
+            yield resp
 
     # -- endpoints ------------------------------------------------------
     def submit(self, process_list: ProcessList | dict | list, *,
@@ -226,19 +240,22 @@ class PipelineClient:
     def result(self, job_id: str, dataset: str | None = None
                ) -> np.ndarray:
         """Fetch an output dataset (``GET /jobs/{id}/result``) as a
-        numpy array (npy bytes on the wire, chunk-streamed server-side).
+        numpy array (npy bytes on the wire, streamed in slabs on both
+        sides: the body is read straight into the returned array).
 
         Args:
             dataset: dataset name; default = the chain's saver output.
 
         Raises:
             ServiceError: 404 unknown job/dataset or evicted result,
-                409 the job is not done yet.
+                409 the job is not done yet; or a body that is not
+                one whole ``.npy`` (cut short, or not the length its
+                header and ``Content-Length`` give).
         """
         q = f"?dataset={quote(dataset, safe='')}" if dataset else ""
-        payload = self._request(
-            "GET", f"/jobs/{quote(job_id, safe='')}/result{q}", raw=True)
-        return np.load(io.BytesIO(payload))
+        with self._open(
+                "GET", f"/jobs/{quote(job_id, safe='')}/result{q}") as resp:
+            return _read_npy(resp)
 
     # -- streaming acquisition (docs/streaming.md) -----------------------
     def ingest(self, job_id: str, frames: np.ndarray,
@@ -614,3 +631,37 @@ class PipelineClient:
                     f"job {job_id!r} still {snap['status']!r} after "
                     f"{timeout}s")
             time.sleep(poll)
+
+
+def _read_npy(resp) -> np.ndarray:
+    """An ``.npy`` body read from the open response into one array
+    allocated up front (``readinto`` its buffer, no intermediate
+    ``bytes``); anything but the whole body raises ``ServiceError``."""
+    try:
+        version = npy_format.read_magic(resp)
+        read_header = {(1, 0): npy_format.read_array_header_1_0,
+                       (2, 0): npy_format.read_array_header_2_0}[version]
+        shape, fortran, dtype = read_header(resp)
+    except (ValueError, KeyError) as e:
+        raise ServiceError(resp.status, f"malformed .npy result: {e}") \
+            from None
+    nbytes = int(np.prod(shape)) * dtype.itemsize
+    if dtype.hasobject or resp.length != nbytes:
+        # resp.length: what Content-Length leaves after the header
+        raise ServiceError(
+            resp.status, f".npy result of {nbytes} bytes of data in a "
+            f"body with {resp.length} bytes after its header")
+    buf = np.empty(nbytes, np.uint8)
+    view, got = memoryview(buf), 0
+    try:
+        while got < nbytes:
+            n = resp.readinto(view[got:])
+            if not n:
+                break
+            got += n
+    except (ConnectionError, http.client.HTTPException):
+        pass
+    if got < nbytes:
+        raise ServiceError(resp.status, f"result body cut short: {got} of "
+                                        f"{nbytes} bytes of data")
+    return buf.view(dtype).reshape(shape, order="F" if fortran else "C")

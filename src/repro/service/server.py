@@ -70,11 +70,14 @@ Endpoints (JSON unless noted; see ``docs/service.md``):
 Results are streamed straight out of the transport's chunk-addressed
 files (``ChunkedFile`` — the checkpoint layer's on-disk layout) one
 chunk-row slab at a time, so serving a large reconstruction never holds
-the dense volume in server RAM; only in-memory/sharded backings are
-materialised before the write.
+the dense volume in server RAM.  A device-resident result goes out in
+axis-0 slabs of about ``RESULT_SLAB_BYTES``, each copied to the host
+while the one before it is written.
 """
 from __future__ import annotations
 
+import collections
+import functools
 import hmac
 import io
 import json
@@ -95,7 +98,8 @@ from ..obs.export import trace_to_otlp
 from ..obs.log import EventLog
 from ..obs.metrics import MetricsRegistry, register_catalogue
 from ..obs.slo import SloEngine
-from ..obs.trace import Span, TraceSpool, render_gantt, use_trace
+from ..obs.trace import (Span, TraceSpool, render_gantt, traced,
+                         use_trace)
 from .checkpoint import CheckpointStore
 from .compile_cache import CompileCache
 from .job import Job, JobState
@@ -671,6 +675,72 @@ def _npy_header(shape: tuple[int, ...], dtype) -> bytes:
     return buf.getvalue()     # write_array_header_1_0 includes the magic
 
 
+#: Bytes of one slab of a result on its way to the socket: the copy of
+#: the next slab to the host runs while this one is written, so a fetch
+#: holds at most two slabs' extra on the device.
+RESULT_SLAB_BYTES = 64 << 20
+
+
+def _slab_bounds(shape: tuple[int, ...], dtype) -> list[tuple[int, int]]:
+    """``[(start, stop), ...]`` along axis 0: whole leading-axis rows,
+    about :data:`RESULT_SLAB_BYTES` each and at least one row; one slab
+    for a 0-d or empty result."""
+    if not shape:
+        return [(0, 1)]
+    row = np.dtype(dtype).itemsize * int(np.prod(shape[1:]))
+    step = max(1, RESULT_SLAB_BYTES // max(row, 1))
+    return [(i, min(i + step, shape[0]))
+            for i in range(0, shape[0], step)] or [(0, 0)]
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _device_slab(arr, start, rows):
+    # ``start`` is traced, so every slab of one size is one program
+    return jax.lax.dynamic_slice_in_dim(arr, start, rows, axis=0)
+
+
+def _host_slabs(arr, bounds):
+    """Each slab of ``arr`` on the host, in order.  A device array's
+    slabs are copied with ``copy_to_host_async``, at most two in flight;
+    the wait for each is a ``transfer.d2h`` span with its ``bytes``.
+    A single slab is the array itself, with no slicing."""
+    if not isinstance(arr, jax.Array):
+        arr = np.asarray(arr)
+        for a, b in bounds:
+            yield arr[a:b] if arr.ndim else arr
+        return
+    whole = len(bounds) == 1
+    pending = collections.deque()
+
+    def start(i):
+        a, b = bounds[i]
+        slab = arr if whole else _device_slab(arr, a, b - a)
+        slab.copy_to_host_async()
+        pending.append(slab)
+
+    for i in range(min(2, len(bounds))):
+        start(i)
+    for i in range(len(bounds)):
+        slab = pending.popleft()
+        with traced("transfer.d2h", bytes=slab.nbytes):
+            host = np.asarray(slab)
+        del slab                        # frees the slab's device copy
+        if i + 2 < len(bounds):
+            start(i + 2)
+        yield host
+
+
+def _descendants(spans: list[Span], root: Span) -> list[Span]:
+    """The spans under ``root`` at any depth (``spans`` sorted by start,
+    as ``Trace.spans`` gives them)."""
+    ids, out = {root.span_id}, []
+    for s in spans:
+        if s.parent_id in ids:
+            ids.add(s.span_id)
+            out.append(s)
+    return out
+
+
 class _PipelineHandler(BaseHTTPRequestHandler):
     """Routes HTTP verbs to the bound :class:`PipelineService`."""
 
@@ -1235,7 +1305,7 @@ class _PipelineHandler(BaseHTTPRequestHandler):
             remote = svc.result_file(job_id, dataset)
             if remote is not None:        # broker mode: stream the file
                 return self._send_result_file(remote[1], remote[0])
-            ds, transport = svc.result_dataset(job_id, dataset)
+            ds, _ = svc.result_dataset(job_id, dataset)
             trace = svc.queue.job(job_id).trace
         except KeyError as e:
             return self._error(404, str(e))
@@ -1243,16 +1313,17 @@ class _PipelineHandler(BaseHTTPRequestHandler):
             return self._error(409, str(e))
         with use_trace(trace), trace.span("result.fetch",
                                           dataset=ds.name) as fetch:
-            self._stream_result(ds, transport, trace)
-        observe_spans(svc.metrics, [s for s in trace.spans()
-                                    if s.parent_id == fetch.span_id])
+            self._stream_result(ds, trace)
+        observe_spans(svc.metrics, _descendants(trace.spans(), fetch))
 
-    def _stream_result(self, ds, transport: Transport, trace) -> None:
-        """The result as ``.npy``: the device's tail of the job
-        (``result.device_wait``), the copy to the host (the transport's
-        ``transfer.d2h``), then the header and bytes (``result.send``)."""
+    def _stream_result(self, ds, trace) -> None:
+        """The result as ``.npy``.  A checkpoint-layer file goes out one
+        chunk-row slab at a time; any other backing waits for the
+        device's tail of the job (``result.device_wait``), then goes out
+        in axis-0 slabs inside ``result.send``, each slab's copy to the
+        host under way while the one before it is written."""
         header = _npy_header(ds.shape, ds.dtype)
-        backing = ds.backing
+        backing = ds.materialise()
         if isinstance(backing, ChunkedFile):
             with trace.span("result.send"):
                 self._send_npy_head(header, ds)
@@ -1268,10 +1339,13 @@ class _PipelineHandler(BaseHTTPRequestHandler):
             return
         with trace.span("result.device_wait"):
             jax.block_until_ready(backing)
-        arr = np.ascontiguousarray(np.asarray(transport.read(ds)))
-        with trace.span("result.send"):
+        bounds = _slab_bounds(ds.shape, ds.dtype)
+        with trace.span("result.send", slabs=len(bounds)):
             self._send_npy_head(header, ds)
-            self.wfile.write(arr.tobytes())
+            for host in _host_slabs(backing, bounds):
+                # the slab's own bytes: no copy where it is C-ordered
+                self.wfile.write(memoryview(
+                    np.ascontiguousarray(host).reshape(-1).view(np.uint8)))
 
     def _send_npy_head(self, header: bytes, ds) -> None:
         self.send_response(200)

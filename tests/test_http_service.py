@@ -5,7 +5,11 @@ ephemeral port, PipelineClient submissions at mixed priorities polled to
 completion with results bit-identical to a serial PluginRunner; 429 on
 admission rejection; 400 with the validation error for malformed specs;
 compile-cache hits visible in GET /stats on identical resubmission."""
+import io
 import json
+import os
+import subprocess
+import sys
 import urllib.error
 import urllib.request
 
@@ -15,9 +19,11 @@ import pytest
 import jax
 from jax.sharding import Mesh
 
-from repro.core import ChunkedFileTransport, PluginRunner, ShardedTransport
+from repro.core import (ChunkedFileTransport, DataSet, PluginRunner,
+                        ShardedTransport)
 from repro.service import (CompileCache, PipelineClient, PipelineService,
                            ServiceError, to_spec)
+from repro.service import server as server_module
 from repro.tomo import standard_chain
 
 N = dict(n_det=20, n_angles=20, n_rows=1)
@@ -267,8 +273,9 @@ def test_spec_submission_equals_processlist_submission(service):
 
 def test_result_fetch_is_traced_with_its_transfer(service):
     """A result fetch lands on the job's trace as ``result.fetch`` with
-    three disjoint children: the device wait, the copy to the host and
-    the send; the copy's bytes reach ``transfer.d2h_bytes``."""
+    two disjoint children: the device wait, then the send; the send
+    holds the slabs' copies to the host (``transfer.d2h``), whose bytes
+    reach ``transfer.d2h_bytes``."""
     svc, client = service
     jid = client.submit(_chain(seed=3))
     assert client.wait(jid, timeout=300)["state"] == "done"
@@ -276,16 +283,16 @@ def test_result_fetch_is_traced_with_its_transfer(service):
     vol = client.result(jid)
     spans = client.trace(jid)["spans"]
     (fetch,) = [s for s in spans if s["name"] == "result.fetch"]
-    children = sorted((s for s in spans
-                       if s.get("parent_id") == fetch["span_id"]),
-                      key=lambda s: s["start"])
+    children = _children(spans, fetch)
     assert [s["name"] for s in children] == [
-        "result.device_wait", "transfer.d2h", "result.send"]
-    for a, b in zip(children, children[1:]):
-        assert a["end"] <= b["start"]
-    assert fetch["start"] <= children[0]["start"]
-    assert children[-1]["end"] <= fetch["end"]
-    assert children[1]["attrs"]["bytes"] == vol.nbytes
+        "result.device_wait", "result.send"]
+    wait, send = children
+    assert fetch["start"] <= wait["start"] <= wait["end"] <= send["start"]
+    assert send["end"] <= fetch["end"]
+    copies = _children(spans, send)
+    assert [s["name"] for s in copies] == ["transfer.d2h"] \
+        * send["attrs"]["slabs"]
+    assert sum(s["attrs"]["bytes"] for s in copies) == vol.nbytes
     assert svc.metrics.counter("transfer.d2h_bytes").value - before \
         == vol.nbytes
     assert "transfer_d2h_bytes" in client.metrics()
@@ -295,3 +302,122 @@ def test_result_fetch_is_traced_with_its_transfer(service):
     plugin = [s for s in spans if s["name"].startswith("plugin.")]
     assert plugin and all(s["parent_id"] == run["span_id"] for s in plugin)
     assert svc.metrics.counter("transfer.h2d_bytes").value > 0
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans
+                   if s.get("parent_id") == parent["span_id"]),
+                  key=lambda s: s["start"])
+
+
+def _serve_probe(svc, client, array, on_device=True):
+    """A finished job whose extra dataset ``probe`` holds ``array`` (on
+    the device, or as a host array): its id."""
+    jid = client.submit(_chain(seed=4))
+    assert client.wait(jid, timeout=300)["state"] == "done"
+    ds = DataSet("probe", array.shape, array.dtype,
+                 tuple(f"a{i}" for i in range(array.ndim)))
+    ds.backing = jax.device_put(array) if on_device else array
+    svc.queue.job(jid).runner.datasets["probe"] = ds
+    return jid
+
+
+@pytest.mark.parametrize("shape, dtype, slab_rows, on_device", [
+    ((4, 6), np.float32, None, True),         # one slab
+    ((7, 3, 5), np.float32, 3, True),         # 3 + 3 + 1 rows
+    ((5, 4), np.uint16, 2, True),             # 2 + 2 + 1, not float32
+    ((3, 2, 2), np.complex64, 1, True),       # a slab a row
+    ((7, 3, 5), np.float32, 3, False),        # a host array, no copies
+])
+def test_result_streams_in_slabs(service, monkeypatch, shape, dtype,
+                                 slab_rows, on_device):
+    """A result goes out in axis-0 slabs of ``RESULT_SLAB_BYTES``: it
+    comes back equal, its body is ``np.save``'s bytes, ``result.send``
+    says how many slabs it sent, and a device result's slab copies
+    (``transfer.d2h``) add up to its bytes."""
+    svc, client = service
+    rng = np.random.default_rng(7)
+    array = (rng.standard_normal(shape) * 1000).astype(dtype)
+    row = array[0].nbytes
+    if slab_rows is not None:
+        monkeypatch.setattr(server_module, "RESULT_SLAB_BYTES",
+                            slab_rows * row + row // 2)
+    n_slabs = -(-shape[0] // (slab_rows or shape[0]))
+    jid = _serve_probe(svc, client, array, on_device)
+    got = client.result(jid, dataset="probe")
+    assert got.dtype == array.dtype and got.shape == array.shape
+    np.testing.assert_array_equal(got, array)
+    url = f"{client.base_url}/jobs/{jid}/result?dataset=probe"
+    with urllib.request.urlopen(url, timeout=30) as resp:
+        body = resp.read()
+    want = io.BytesIO()
+    np.save(want, array)
+    assert body == want.getvalue()
+
+    spans = client.trace(jid)["spans"]
+    fetch = next(s for s in spans if s["name"] == "result.fetch"
+                 and s["attrs"]["dataset"] == "probe")
+    (send,) = [s for s in _children(spans, fetch)
+               if s["name"] == "result.send"]
+    assert send["attrs"]["slabs"] == n_slabs
+    copies = [s["attrs"]["bytes"] for s in _children(spans, send)
+              if s["name"] == "transfer.d2h"]
+    if on_device:
+        assert len(copies) == n_slabs and sum(copies) == array.nbytes
+        assert copies[0] == (slab_rows or shape[0]) * row
+    else:
+        assert copies == []
+
+
+def test_result_body_cut_short_raises(service, monkeypatch):
+    """A server that stops mid-body (here: the second slab fails) gives
+    the client a short body, which raises ``ServiceError``; no partly
+    filled array comes back."""
+    svc, client = service
+    array = np.arange(6 * 4, dtype=np.float32).reshape(6, 4)
+    monkeypatch.setattr(server_module, "RESULT_SLAB_BYTES",
+                        2 * array[0].nbytes)
+    real = server_module._host_slabs
+
+    def first_slab_only(arr, bounds):
+        slabs = real(arr, bounds)
+        yield next(slabs)
+        raise RuntimeError("the device went away")
+
+    monkeypatch.setattr(server_module, "_host_slabs", first_slab_only)
+    jid = _serve_probe(svc, client, array)
+    with pytest.raises(ServiceError, match="cut short: 32 of 96 bytes"):
+        client.result(jid, dataset="probe")
+
+
+_FOUR_DEVICE_SLABS = """
+import jax
+jax.config.update("jax_num_cpu_devices", 4)
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from repro.service import server
+
+mesh = Mesh(np.asarray(jax.devices()), ("data",))
+a = np.arange(8 * 3 * 5, dtype=np.float32).reshape(8, 3, 5)
+on_mesh = jax.device_put(a, NamedSharding(mesh, PartitionSpec("data")))
+server.RESULT_SLAB_BYTES = 3 * a[0].nbytes         # 3 + 3 + 2 rows
+bounds = server._slab_bounds(a.shape, a.dtype)
+assert bounds == [(0, 3), (3, 6), (6, 8)], bounds
+slabs = list(server._host_slabs(on_mesh, bounds))
+assert [s.shape[0] for s in slabs] == [3, 3, 2]
+np.testing.assert_array_equal(np.concatenate(slabs), a)
+"""
+
+
+def test_sharded_result_streams_in_slabs():
+    """A result sharded over a 4-device data mesh goes out in the same
+    axis-0 slabs, ragged last slab included, and comes back whole."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(here), "src"),
+                    os.environ.get("PYTHONPATH", "")]))
+    proc = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_SLABS],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
